@@ -22,8 +22,13 @@ Artifacts:
 * ``results/benchmarks/bench_famsim.json`` — this invocation's full rows
   (the scaffold contract, like every figure);
 * ``results/roofline/famsim_step.json`` — ``repro.roofline`` terms of
-  each backend's compiled group executable (loop-aware HLO costing) next
-  to the measured throughput.
+  each backend's compiled group executable (loop-aware HLO costing),
+  priced at the peaks of the device it ran on, next to the measured
+  throughput. Written only on a TPU: a CPU has no published peaks, so
+  there the roofline is "not measured".
+
+Every row names the device it ran on (``platform``, ``device_kind``,
+``devices``).
 
 Usage (via the ``bench`` subcommand)::
 
@@ -113,6 +118,8 @@ def _measure(backend: str, quick: bool, repeats: int) -> dict:
         "events": info.events,
         "points": len(result.points),
         "devices": info.devices,
+        "platform": info.platform,
+        "device_kind": info.device_kind,
         "planned_groups": info.planned_groups,
         "run_s_best": round(best, 4),
         "run_s_all": [round(r, 4) for r in runs],
@@ -136,7 +143,8 @@ def _roofline_record(measured: dict) -> dict:
     recs = []
     for g, key in zip(plan.groups, keys):
         compiled = _ex._EXEC_CACHE[key]
-        terms = analyze(compiled, chips=measured["devices"], model_flops=0.0)
+        terms = analyze(compiled, chips=measured["devices"], model_flops=0.0,
+                        device_kind=measured["device_kind"])
         recs.append({"static_shape": str(g.key.static_shape),
                      **terms.to_dict()})
     return {
@@ -197,10 +205,14 @@ def main(argv=None) -> None:
             "kernel backends disagree on derived metrics — the fused "
             "kernel must be bit-identical to the XLA path", digests)
 
-    if not args.no_roofline:
+    platform = measured[0]["platform"]
+    if not args.no_roofline and platform == "tpu":
         ROOFLINE.parent.mkdir(parents=True, exist_ok=True)
         ROOFLINE.write_text(json.dumps(
             [_roofline_record(m) for m in measured], indent=2) + "\n")
+    elif not args.no_roofline:
+        print(f"# roofline: not measured on {platform} (peaks are "
+              "published for TPUs only)", flush=True)
 
     rows = []
     for m in measured:

@@ -35,6 +35,7 @@ machinery; new code should declare an ``Experiment``.
 from __future__ import annotations
 
 import json
+import os
 import time
 import warnings
 from contextlib import contextmanager
@@ -51,6 +52,9 @@ from repro.experiments import (ExperimentResult, ResolvedPoint, RunInfo,
                                execute, plan_points, trace_arrays)
 
 RESULTS = Path(__file__).resolve().parent.parent / "results" / "benchmarks"
+#: JAX's persistent compilation cache where ``JAX_COMPILATION_CACHE_DIR``
+#: is not set. Fixed, because the path is part of every entry's key.
+COMPILE_CACHE = RESULTS.parent.parent / ".jax_cache"
 
 # default workload subset (one per suite + the cache/BW-sensitive ones the
 # paper highlights); --full runs all 19
@@ -65,6 +69,19 @@ ADAPT = SimFlags(bw_adapt=True)
 
 def WFQ(w: int) -> SimFlags:
     return SimFlags(wfq=True, wfq_weight=w)
+
+
+def use_compile_cache() -> str:
+    """Keep compiled executables across processes; call before the first
+    compile. JAX reads ``JAX_COMPILATION_CACHE_DIR`` itself, so where it
+    is set this changes nothing; otherwise the cache goes to
+    :data:`COMPILE_CACHE`. Returns the directory in use."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+    jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE))
+    return str(COMPILE_CACHE)
 
 
 # ---------------------------------------------------------------------------

@@ -39,6 +39,30 @@ def experiment(quick: bool = True, trace_backend: str = "device",
               flag_axis("variant", {"base": BASELINE, "dram": DRAM})))
 
 
+def block_rows(res, wls) -> list:
+    """One row per block size: geomean over ``wls`` of the dram variant's
+    IPC gain and FAM latency relative to the base variant."""
+    rows = []
+    for bs in BLOCK_SIZES:
+        gains, rels = [], []
+        for w in wls:
+            base = res.get(block=bs, workload=w, variant="base")
+            out = res.get(block=bs, workload=w, variant="dram")
+            gains.append(float(out["ipc"][0] / max(base["ipc"][0], 1e-9)))
+            rels.append(float(out["fam_latency"][0] /
+                              max(base["fam_latency"][0], 1e-9)))
+        rows.append({
+            "name": f"fig08_block{bs}",
+            "us_per_call": res.info.us_per_call(),
+            "derived": f"ipc_gain={geomean(gains):.3f};"
+                       f"rel_fam_latency={geomean(rels):.3f}",
+            "block_bytes": bs,
+            "ipc_gain_geomean": geomean(gains),
+            "rel_fam_latency_geomean": geomean(rels),
+        })
+    return rows
+
+
 def run(quick: bool = True, trace_backend: str = "device",
         kernel_backend: str = "xla", telemetry: int = 0):
     wls = workloads(quick)
@@ -52,25 +76,7 @@ def run(quick: bool = True, trace_backend: str = "device",
                                         assert_compiles=True)
     info = res.info
     assert info.planned_groups == 1, info.groups  # dynamic geometry: 1 compile
-
-    rows = []
-    for bs in BLOCK_SIZES:
-        gains, rels = [], []
-        for w in wls:
-            base = res.get(block=bs, workload=w, variant="base")
-            out = res.get(block=bs, workload=w, variant="dram")
-            gains.append(float(out["ipc"][0] / max(base["ipc"][0], 1e-9)))
-            rels.append(float(out["fam_latency"][0] /
-                              max(base["fam_latency"][0], 1e-9)))
-        rows.append({
-            "name": f"fig08_block{bs}",
-            "us_per_call": info.us_per_call(),
-            "derived": f"ipc_gain={geomean(gains):.3f};"
-                       f"rel_fam_latency={geomean(rels):.3f}",
-            "block_bytes": bs,
-            "ipc_gain_geomean": geomean(gains),
-            "rel_fam_latency_geomean": geomean(rels),
-        })
+    rows = block_rows(res, wls)
 
     # engine acceptance: batched == per-point within 1e-5, the recorded
     # wall-clock comparison (per-point pays a compile per (flags, shape)),

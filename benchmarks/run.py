@@ -71,6 +71,9 @@ FIGURE_NAMES = ("fig08", "fig10", "fig12", "fig14", "fig15", "fig16")
 
 def main(argv=None) -> None:
     argv = sys.argv[1:] if argv is None else list(argv)
+    from benchmarks.common import use_compile_cache
+    if argv and argv[0] in ("search", "bench", "pond"):
+        use_compile_cache()
     if argv and argv[0] == "search":
         # the search subcommand owns its whole argument tail
         from benchmarks import fig_search
@@ -186,6 +189,7 @@ def main(argv=None) -> None:
                     telemetry=args.telemetry)
         return
 
+    use_compile_cache()
     print("name,us_per_call,derived")
     for key, mod in figures.items():
         t0 = time.time()

@@ -1,5 +1,8 @@
-from repro.core.fam_params import FamParams, stack_params  # noqa: F401
-from repro.core.famsim import (SimFlags, build_sim, build_sweep,  # noqa: F401
-                               simulate, sweep)
-from repro.policies import DEFAULT_POLICY_SET, PolicySet  # noqa: F401
-from repro.core.tiering import TieredBlockPool, TierState  # noqa: F401
+"""The simulator's mechanisms: DRAM cache, FAM controller, SPP, WFQ, the
+IPC model and the event loop that ties them together (``famsim``).
+
+Import the submodules directly (``from repro.core.famsim import
+build_sim``); the package itself imports nothing, so
+``repro.kernels.famsim_step`` can depend on ``repro.core.dram_cache``
+while ``repro.core.famsim`` depends on the kernel package.
+"""

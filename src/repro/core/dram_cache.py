@@ -53,6 +53,17 @@ def _way_mask(state: CacheState, ways):
     return jnp.arange(state.tags.shape[1]) < jnp.asarray(ways)
 
 
+def gather_row(a, i):
+    """Row ``i`` of a 2-D table, read as one element gather per column.
+
+    A plain row gather (``a[i]``) and the element scatters that update
+    these tables ask the TPU compiler for different layouts of the array,
+    so inside a loop it relayouts the whole array on every read (89 % of
+    fig08's compiled step). Read this way, reads and writes share one
+    layout; the values are the same."""
+    return a[i, jnp.arange(a.shape[1])]
+
+
 def lookup(state: CacheState, block_addr, num_sets=None, ways=None
            ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """-> (hit, set_idx, way). Pure query; no state change.
@@ -62,7 +73,7 @@ def lookup(state: CacheState, block_addr, num_sets=None, ways=None
     """
     si = _set_index(block_addr,
                     state.tags.shape[0] if num_sets is None else num_sets)
-    row = state.tags[si]
+    row = gather_row(state.tags, si)
     match = row == (block_addr.astype(jnp.int32) + 1)
     if ways is not None:
         match = match & _way_mask(state, ways)
@@ -108,8 +119,8 @@ def insert(state: CacheState, block_addr, enable=True,
     en = jnp.asarray(enable)
     si = _set_index(block_addr,
                     state.tags.shape[0] if num_sets is None else num_sets)
-    row_tags = state.tags[si]
-    row_lru = state.lru[si]
+    row_tags = gather_row(state.tags, si)
+    row_lru = gather_row(state.lru, si)
     tag = block_addr.astype(jnp.int32) + 1
     already = row_tags == tag
     vacant = row_tags == 0
@@ -155,7 +166,8 @@ def insert(state: CacheState, block_addr, enable=True,
     new_row = base_row.at[way].set(fill_val)
     new = CacheState(
         tags=state.tags.at[si, way].set(jnp.where(en, tag, row_tags[way])),
-        lru=state.lru.at[si].set(jnp.where(en, new_row, row_lru)),
+        lru=state.lru.at[si, jnp.arange(w_pad)].set(
+            jnp.where(en, new_row, row_lru)),
         stamp=stamp)
     return new, evicted, si * w_pad + way
 

@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import FamConfig
+from repro.core.dram_cache import gather_row
 
 SIG_SHIFT = 4
 PT_WAYS = 4
@@ -81,8 +82,8 @@ def update(cfg: FamConfig, s: SppState, page, block, enable=True
 
     # --- pattern table update (only on ST hit with nonzero delta)
     pt_i = _pt_index(cfg, old_sig)
-    row_d = s.pt_delta[pt_i]
-    row_w = s.pt_weight[pt_i]
+    row_d = gather_row(s.pt_delta, pt_i)
+    row_w = gather_row(s.pt_weight, pt_i)
     match = row_d == delta
     has_match = jnp.any(match & (row_w > 0))
     way = jnp.where(has_match,
@@ -91,8 +92,9 @@ def update(cfg: FamConfig, s: SppState, page, block, enable=True
     new_w = jnp.where(has_match, jnp.minimum(row_w[way] + 1, MAX_WEIGHT), 1)
     row_d = row_d.at[way].set(jnp.where(train, delta, row_d[way]))
     row_w = row_w.at[way].set(jnp.where(train, new_w, row_w[way]))
-    pt_delta = s.pt_delta.at[pt_i].set(row_d)
-    pt_weight = s.pt_weight.at[pt_i].set(row_w)
+    ways = jnp.arange(PT_WAYS)
+    pt_delta = s.pt_delta.at[pt_i, ways].set(row_d)
+    pt_weight = s.pt_weight.at[pt_i, ways].set(row_w)
     pt_sigw = s.pt_sigw.at[pt_i].add(
         jnp.where(train, jnp.where(s.pt_sigw[pt_i] < 4 * MAX_WEIGHT, 1, 0), 0))
 
@@ -124,8 +126,8 @@ def predict(cfg: FamConfig, s: SppState, page, block, sig, degree: int,
     def body(carry, _):
         cur_sig, cur_block, conf, alive = carry
         pt_i = _pt_index(cfg, cur_sig)
-        row_w = s.pt_weight[pt_i]
-        row_d = s.pt_delta[pt_i]
+        row_w = gather_row(s.pt_weight, pt_i)
+        row_d = gather_row(s.pt_delta, pt_i)
         way = jnp.argmax(row_w)
         w = row_w[way]
         sigw = jnp.maximum(s.pt_sigw[pt_i], 1)

@@ -92,6 +92,10 @@ class RunInfo:
     padded_events: int = 0         # extra events paid to T/S padding
     padded_systems: int = 0        # inert systems added for canonical S
     devices: int = 1
+    #: ``jax.devices()[0]``'s platform and kind: every timing above was
+    #: taken on this device
+    platform: str = ""
+    device_kind: str = ""
     trace_backend: str = DEFAULT_BACKEND
     #: events actually GENERATED host-side (memoized trace-cache reuse is
     #: free, padded lanes repeat real systems): 0 = the no-host fast path
@@ -123,6 +127,8 @@ class RunInfo:
              "padded_events": self.padded_events,
              "padded_systems": self.padded_systems,
              "devices": self.devices,
+             "platform": self.platform,
+             "device_kind": self.device_kind,
              "trace_backend": self.trace_backend,
              "host_trace_events": self.host_trace_events,
              "trace_gen_s": round(self.trace_gen_s, 4),
@@ -308,6 +314,39 @@ def group_cache_keys(plan: Plan, *, devices: Optional[int] = None,
     return tuple(keys)
 
 
+def group_program(cfg, S: int, N: int, t_pad: int, *, pad_sets: int,
+                  pad_ways: int, trace_backend: str, policies):
+    """One group's runner, vmapped over its S systems, and the abstract
+    arguments it is compiled for: ``(params, *inputs, t_true,
+    warm_start)``. :func:`_compiled` jits it (sharded when ``mode`` asks);
+    ``tests/test_tpu_compile.py`` compiles the same program for a
+    described TPU."""
+    import jax
+    import jax.numpy as jnp
+
+    i32 = jnp.int32
+    if trace_backend == "device":
+        from repro.traces.device import abstract_params, node_generator
+        fn = build_masked_vmap(cfg, N, pad_sets, pad_ways,
+                               trace_gen=node_generator(t_pad),
+                               trace_key=("device", t_pad),
+                               policies=policies)
+        input_shapes = (abstract_params(S, N),)
+    else:
+        fn = build_masked_vmap(cfg, N, pad_sets, pad_ways,
+                               policies=policies)
+        input_shapes = (
+            jax.ShapeDtypeStruct((S, N, t_pad), i32),
+            jax.ShapeDtypeStruct((S, N, t_pad), jnp.float32))
+    p_proto = FamParams.of(cfg, policies=policies)
+    params_shape = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct((S,) + jnp.shape(x), x.dtype),
+        p_proto)
+    return fn, (params_shape, *input_shapes,
+                jax.ShapeDtypeStruct((S,), i32),
+                jax.ShapeDtypeStruct((S,), i32))
+
+
 def _compiled(cfg, S: int, N: int, t_pad: int, mode,
               info: Optional[RunInfo] = None, *,
               pad_sets: Optional[int] = None, pad_ways: Optional[int] = None,
@@ -324,14 +363,12 @@ def _compiled(cfg, S: int, N: int, t_pad: int, mode,
     construction), and it donates the policy numeric-param *schema* for
     the abstract shapes."""
     import jax
-    import jax.numpy as jnp
 
     from repro.policies import DEFAULT_POLICY_SET
 
     policies = policies or DEFAULT_POLICY_SET
     pad_sets = pad_sets or cfg.num_sets
     pad_ways = pad_ways or cfg.cache_ways
-    in_graph = trace_backend == "device"
     key = _exec_key(cfg, S, N, t_pad, mode, pad_sets=pad_sets,
                     pad_ways=pad_ways, trace_backend=trace_backend,
                     policies=policies)
@@ -341,20 +378,9 @@ def _compiled(cfg, S: int, N: int, t_pad: int, mode,
         else:
             info.exec_cache_misses += 1
     if key not in _EXEC_CACHE:
-        i32 = jnp.int32
-        if in_graph:
-            from repro.traces.device import abstract_params, node_generator
-            fn = build_masked_vmap(cfg, N, pad_sets, pad_ways,
-                                   trace_gen=node_generator(t_pad),
-                                   trace_key=("device", t_pad),
-                                   policies=policies)
-            input_shapes = (abstract_params(S, N),)
-        else:
-            fn = build_masked_vmap(cfg, N, pad_sets, pad_ways,
-                                   policies=policies)
-            input_shapes = (
-                jax.ShapeDtypeStruct((S, N, t_pad), i32),
-                jax.ShapeDtypeStruct((S, N, t_pad), jnp.float32))
+        fn, arg_shapes = group_program(
+            cfg, S, N, t_pad, pad_sets=pad_sets, pad_ways=pad_ways,
+            trace_backend=trace_backend, policies=policies)
         if mode != "vmap":
             from jax.sharding import PartitionSpec as P
 
@@ -363,10 +389,6 @@ def _compiled(cfg, S: int, N: int, t_pad: int, mode,
             mesh = compat.make_mesh((D,), ("dev",))
             fn = compat.shard_map(fn, mesh=mesh, in_specs=P("dev"),
                                   out_specs=P("dev"))
-        p_proto = FamParams.of(cfg, policies=policies)
-        params_shape = jax.tree.map(
-            lambda x: jax.ShapeDtypeStruct((S,) + jnp.shape(x), x.dtype),
-            p_proto)
         # every group executable is jitted under the canonical name
         # prefix so the runtime CompileWatcher (repro.analysis.runtime)
         # can count real group compiles in jax's log_compiles stream,
@@ -382,10 +404,7 @@ def _compiled(cfg, S: int, N: int, t_pad: int, mode,
         t0 = time.perf_counter()
         with maybe_span("compile", key_digest=_key_digest(key),
                         S=S, N=N, T_pad=t_pad):
-            compiled = jax.jit(famsim_group).lower(
-                params_shape, *input_shapes,
-                jax.ShapeDtypeStruct((S,), i32),
-                jax.ShapeDtypeStruct((S,), i32)).compile()
+            compiled = jax.jit(famsim_group).lower(*arg_shapes).compile()
         dt = time.perf_counter() - t0
         _EXEC_CACHE[key] = compiled
         if info is not None:
@@ -470,7 +489,9 @@ def execute(plan: Plan, *, devices: Optional[int] = None,
 
     backend = validate_backend(trace_backend or plan.trace_backend)
     D = len(jax.devices()) if devices is None else devices
+    dev = jax.devices()[0]
     info = RunInfo(planned_groups=plan.num_groups, devices=D,
+                   platform=dev.platform, device_kind=dev.device_kind,
                    trace_backend=backend)
 
     exec_idxs = [_pad_systems(g.indices, g.s_pad, D) for g in plan.groups]

@@ -11,8 +11,8 @@ emits per event.
 (the default) runs the pure-XLA reference in :mod:`ref` — the exact
 ``repro.core.dram_cache`` op sequence the classic simulator used —
 while ``backend="pallas"`` runs the fused kernel in :mod:`kernel`
-(``interpret=True`` off-TPU), bit-identical by property test
-(``tests/test_famsim_step.py``).
+(interpreted when the program is lowered for a platform other than
+TPU), bit-identical by property test (``tests/test_famsim_step.py``).
 """
 from repro.kernels.famsim_step.kernel import fused_cache_step
 from repro.kernels.famsim_step.ops import (FUSED_REPLACEMENT_MODES,

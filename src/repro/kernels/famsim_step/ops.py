@@ -3,14 +3,17 @@
 ``cache_step`` is what famsim calls once per node per event. The
 ``backend`` tag is STATIC (it rides on ``FamConfig.kernel_backend`` and
 therefore on every compile key): ``"xla"`` runs the dram_cache reference
-sequence, ``"pallas"`` the fused kernel — compiled on TPU, interpreted
-(and still jit-compatible) elsewhere, bit-identical either way.
+sequence, ``"pallas"`` the fused kernel — compiled when the program is
+lowered for TPU, interpreted (and still jit-compatible) when it is
+lowered for any other platform, bit-identical either way.
 
 The fused kernel bakes the replacement policy in as a static mode, so
 only policies that declare ``fused_mode`` ("lru", "srrip") can ride it;
 ``random`` needs threefry inside the update and stays XLA-only.
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 
@@ -50,9 +53,15 @@ def cache_step(cache: dc.CacheState, fill_blocks, fill_enable,
         raise ValueError(f"unknown kernel backend {backend!r}; expected "
                          f"one of {KERNEL_BACKENDS}")
     mode, max_rrpv = fused_replacement_mode(policy)
-    tags, lru, stamp, hit, probe_hits = fused_cache_step(
+
+    def kernel(interpret):
+        return functools.partial(fused_cache_step, mode=mode,
+                                 max_rrpv=max_rrpv, interpret=interpret)
+
+    # chosen by the platform the program is lowered for: compiled on TPU,
+    # interpreted anywhere else
+    tags, lru, stamp, hit, probe_hits = jax.lax.platform_dependent(
         cache.tags, cache.lru, cache.stamp, fill_blocks, fill_enable,
         demand_block, demand_enable, probe_blocks, num_sets, ways,
-        mode=mode, max_rrpv=max_rrpv,
-        interpret=jax.default_backend() != "tpu")
+        tpu=kernel(False), default=kernel(True))
     return dc.CacheState(tags, lru, stamp), hit, probe_hits

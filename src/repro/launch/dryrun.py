@@ -34,6 +34,8 @@ from repro.train.steps import (abstract_train_state, build_decode_step,
 import dataclasses
 
 RESULTS_DIR = Path(__file__).resolve().parents[3] / "results" / "dryrun"
+#: the chip the production mesh is planned for; prices the roofline terms
+TARGET_KIND = "TPU v5 lite"
 
 # activation budget for picking microbatch count (bytes per device)
 _ACT_BUDGET = 2 << 30
@@ -169,7 +171,8 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool, *,
     t_compile = time.time() - t0
 
     mem = compiled.memory_analysis()
-    terms = analyze(compiled, chips, model_flops_for(cfg, shape))
+    terms = analyze(compiled, chips, model_flops_for(cfg, shape),
+                    TARGET_KIND)
     info = {
         "arch": arch, "shape": shape_name,
         "mesh": "2x16x16" if multi_pod else "16x16", "chips": chips,

@@ -30,8 +30,9 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import SHAPES_BY_NAME
 from repro.configs.registry import get_config
-from repro.launch.dryrun import (_shardings, abstract_train_state,
-                                 make_context, model_flops_for)
+from repro.launch.dryrun import (TARGET_KIND, _shardings,
+                                 abstract_train_state, make_context,
+                                 model_flops_for)
 from repro.launch.mesh import make_production_mesh
 from repro.models import transformer as T
 from repro.models.model_zoo import batch_specs, build_model, cache_specs
@@ -41,7 +42,7 @@ RESULTS = Path(__file__).resolve().parents[3] / "results" / "perf"
 
 
 def record(cell: str, variant: str, compiled, chips, model_flops, extra=None):
-    terms = analyze(compiled, chips, model_flops)
+    terms = analyze(compiled, chips, model_flops, TARGET_KIND)
     mem = compiled.memory_analysis()
     info = {"cell": cell, "variant": variant,
             "roofline": terms.to_dict(),
@@ -117,7 +118,7 @@ def qwen_buffered(window: int = 64, kv_dtype="bfloat16"):
     fl = jax.jit(flush, in_shardings=(cache_sh, buf_sh, None),
                  out_shardings=cache_sh, donate_argnums=0)
     flushed = fl.lower(cache, buffer, scalars).compile()
-    f_terms = analyze(flushed, chips, 0.0)
+    f_terms = analyze(flushed, chips, 0.0, TARGET_KIND)
 
     variant = f"buffered_w{window}" + ("_int8" if kvdt == jnp.int8 else "")
     info = record("qwen2-vl-72b__decode_32k", variant, compiled, chips,

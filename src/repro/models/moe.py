@@ -106,8 +106,7 @@ def _dispatch_compute_local(cfg: ModelConfig, ep_axis: str, capacity: int,
     T, d = x_flat.shape
     k = m.top_k
     E = m.num_experts
-    from repro.parallel.compat import axis_size
-    M = axis_size(ep_axis)
+    M = jax.lax.axis_size(ep_axis)
     E_loc = E // M
     C = capacity
 

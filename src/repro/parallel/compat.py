@@ -1,47 +1,19 @@
-"""Version-portable wrappers for jax APIs that moved between 0.4.x and 0.5+.
-
-The repo targets the newest API surface (``jax.shard_map``,
-``jax.sharding.AxisType``, dict-valued ``cost_analysis``), but must run on
-the 0.4.x line too — these shims pick whichever spelling the installed jax
-provides. Keep every such branch here so the rest of the codebase stays on
-one idiom.
-"""
+"""The repo's one spelling of the JAX sharding APIs it uses with
+non-default settings (JAX 0.9, see requirements-dev.txt)."""
 from __future__ import annotations
 
 import jax
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None):
-    """``jax.make_mesh`` with explicit-Auto axis types where supported
-    (0.4.x has no ``axis_types`` and is implicitly Auto)."""
-    kw = {}
-    if hasattr(jax.sharding, "AxisType"):
-        kw["axis_types"] = (jax.sharding.AxisType.Auto,) * len(axis_names)
-    return jax.make_mesh(axis_shapes, axis_names, devices=devices, **kw)
+    """``jax.make_mesh`` with Auto axes (JAX's default is Explicit)."""
+    return jax.make_mesh(
+        axis_shapes, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
+        devices=devices)
 
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` (0.5+, ``check_vma``) or the experimental export
-    (0.4.x, ``check_rep``), always with replication checking off."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
-def axis_size(name: str):
-    """``jax.lax.axis_size`` (0.5+); on 0.4.x, psum of a unit literal folds
-    to the mapped axis size without emitting a collective."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(name)
-    return jax.lax.psum(1, name)
-
-
-def cost_analysis_dict(compiled) -> dict:
-    """``compiled.cost_analysis()`` as a dict (0.4.x wraps it in a list)."""
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
+    """``jax.shard_map`` with replication checking off."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

@@ -21,11 +21,33 @@ import re
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
-# TPU v5e-class hardware constants (per assignment)
-PEAK_FLOPS = 197e12          # bf16 FLOP/s per chip
-HBM_BW = 819e9               # bytes/s per chip
-ICI_BW = 50e9                # bytes/s per link
-ICI_LINKS = 4                # usable links per chip on a 2D torus (v5e-like)
+
+@dataclass(frozen=True)
+class Peaks:
+    """Published per-chip peaks of one device kind."""
+
+    flops: float         # bf16 FLOP/s
+    hbm_bw: float        # bytes/s
+    ici_bw: float        # bytes/s per link
+    ici_links: int
+
+
+#: Keyed by ``jax.Device.device_kind``. Source: Google Cloud
+#: documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s
+#: of chip-to-chip interconnect (4 links of 50 GB/s).
+PEAKS = {"TPU v5 lite": Peaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9,
+                              ici_links=4)}
+
+
+def peaks(device_kind: str) -> Peaks:
+    """The peaks of ``device_kind``; a kind not in :data:`PEAKS` is an
+    error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r} (known: {sorted(PEAKS)})") from None
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
@@ -95,19 +117,25 @@ class RooflineTerms:
     bytes_per_device: float
     collective_bytes_per_device: float
     chips: int
+    device_kind: str             # keys PEAKS
     model_flops: float = 0.0     # 6*N*D (train) or 2*N_active*D (serve), global
 
     @property
+    def peaks(self) -> Peaks:
+        return peaks(self.device_kind)
+
+    @property
     def compute_s(self) -> float:
-        return self.flops_per_device / PEAK_FLOPS
+        return self.flops_per_device / self.peaks.flops
 
     @property
     def memory_s(self) -> float:
-        return self.bytes_per_device / HBM_BW
+        return self.bytes_per_device / self.peaks.hbm_bw
 
     @property
     def collective_s(self) -> float:
-        return self.collective_bytes_per_device / (ICI_LINKS * ICI_BW)
+        return self.collective_bytes_per_device / (
+            self.peaks.ici_links * self.peaks.ici_bw)
 
     @property
     def bottleneck(self) -> str:
@@ -132,7 +160,7 @@ class RooflineTerms:
         t = self.step_time_s
         if t <= 0:
             return 0.0
-        return self.model_flops / (self.chips * PEAK_FLOPS * t)
+        return self.model_flops / (self.chips * self.peaks.flops * t)
 
     def to_dict(self) -> Dict:
         return {
@@ -140,6 +168,7 @@ class RooflineTerms:
             "bytes_per_device": self.bytes_per_device,
             "collective_bytes_per_device": self.collective_bytes_per_device,
             "chips": self.chips,
+            "device_kind": self.device_kind,
             "model_flops": self.model_flops,
             "compute_s": self.compute_s,
             "memory_s": self.memory_s,
@@ -155,23 +184,25 @@ class RooflineTerms:
         }
 
 
-def analyze(compiled, chips: int, model_flops: float) -> RooflineTerms:
-    """Loop-aware analysis of the compiled per-partition module.
+def analyze(compiled, chips: int, model_flops: float,
+            device_kind: str) -> RooflineTerms:
+    """Loop-aware analysis of the compiled per-partition module, priced
+    at the peaks of ``device_kind`` (see :data:`PEAKS`).
 
     Uses repro.roofline.hlo_parse (trip-count-aware) rather than
     ``cost_analysis()``, which counts scan bodies once (see hlo_parse docs);
     cost_analysis values are kept as cross-checks in the dry-run JSON.
     """
     from repro.roofline.hlo_parse import analyze_hlo
+    peaks(device_kind)           # an unknown kind fails before parsing
     cost = analyze_hlo(compiled.as_text())
     terms = RooflineTerms(
         flops_per_device=cost.flops, bytes_per_device=cost.bytes,
         collective_bytes_per_device=cost.collective_bytes,
-        chips=chips, model_flops=model_flops)
+        chips=chips, device_kind=device_kind, model_flops=model_flops)
     terms.coll_bytes = dict(cost.coll_bytes)
     terms.coll_count = dict(cost.coll_count)
-    from repro.parallel.compat import cost_analysis_dict
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis() or {}
     terms.xla_flops_once = float(ca.get("flops", 0.0))
     terms.xla_bytes_once = float(ca.get("bytes accessed", 0.0))
     return terms

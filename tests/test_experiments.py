@@ -11,6 +11,7 @@ import os
 import subprocess
 import sys
 
+import jax
 import numpy as np
 import pytest
 
@@ -395,6 +396,10 @@ def test_info_records_per_group_wallclock(small_result):
     assert info.padded_events == 0          # uniform-T: no padding paid
     d = info.as_dict()
     assert d["shard_check"]["bit_exact"] is True
+    # every timing names the device it was taken on
+    dev = jax.devices()[0]
+    assert (d["platform"], d["device_kind"]) == (dev.platform,
+                                                 dev.device_kind)
 
 
 def test_exec_cache_accounting_two_run_sequence():

@@ -11,6 +11,11 @@ Three contracts, all bit-exact:
 * the backend is a STATIC compile tag: it splits planner compile groups,
   and unsupported policy/backend combinations fail loudly at build time.
 """
+import os
+import subprocess
+import sys
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -90,6 +95,47 @@ def test_fused_cache_step_raw_wrapper_shapes():
     assert phits.shape == (3,) and phits.dtype == jnp.bool_
     assert bool(hit)                      # block 3 was just filled
     np.testing.assert_array_equal(np.asarray(phits), [True, False, True])
+
+
+def test_fused_cache_step_nested_vmap():
+    """vmap over systems of vmap over nodes folds into one batched kernel
+    call (custom_vmap); every cache still matches the reference, with the
+    effective geometry shared across nodes (unbatched at the inner
+    level)."""
+    S_, N_, C, P = 2, 3, 3, 4
+    rng = np.random.default_rng(7)
+    tags = jnp.asarray(rng.integers(0, 40, (S_, N_, 16, 4)), jnp.int32)
+    lru = jnp.asarray(rng.integers(0, 9, (S_, N_, 16, 4)), jnp.int32)
+    stamp = jnp.full((S_, N_), 9, jnp.int32)
+    fills = jnp.asarray(rng.integers(0, 60, (S_, N_, C)), jnp.int32)
+    fen = jnp.asarray(rng.random((S_, N_, C)) < 0.7)
+    demand = jnp.asarray(rng.integers(0, 60, (S_, N_)), jnp.int32)
+    den = jnp.ones((S_, N_), bool)
+    probes = jnp.asarray(rng.integers(0, 60, (S_, N_, P)), jnp.int32)
+    sets = jnp.asarray([16, 11], jnp.int32)
+    ways = jnp.asarray([4, 3], jnp.int32)
+
+    def run(step):
+        def node(t, l, st, f, fe, d, de, p, ns, w):
+            c, hit, ph = step(dc.CacheState(t, l, st), f, fe, d, de, p,
+                              ns, w)
+            return (*c, hit, ph)
+        per_system = jax.vmap(node, in_axes=(0,) * 8 + (None, None))
+        return jax.vmap(per_system)(tags, lru, stamp, fills, fen, demand,
+                                    den, probes, sets, ways)
+
+    ref = run(cache_step_ref)
+    fused = run(lambda *a: cache_step(*a, backend="pallas"))
+    for a, b in zip(ref, fused):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_kernel_package_imports_before_core():
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    subprocess.run(
+        [sys.executable, "-c", "import repro.kernels.famsim_step"],
+        env=dict(os.environ, JAX_PLATFORMS="cpu",
+                 PYTHONPATH=os.path.join(root, "src")), check=True)
 
 
 # ---------------------------------------------------------------------------

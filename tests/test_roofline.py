@@ -5,7 +5,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.parallel.compat import cost_analysis_dict
 from repro.roofline.hlo_parse import analyze_hlo
 
 
@@ -19,7 +18,7 @@ def test_matmul_flops_match_xla():
     B = jax.ShapeDtypeStruct((K, N), jnp.float32)
     comp = _compile(lambda a, b: a @ b, A, B)
     cost = analyze_hlo(comp.as_text())
-    xla_flops = cost_analysis_dict(comp)["flops"]
+    xla_flops = comp.cost_analysis()["flops"]
     assert abs(cost.flops - 2 * M * K * N) / (2 * M * K * N) < 0.01
     assert abs(cost.flops - xla_flops) / xla_flops < 0.05
 
@@ -39,7 +38,7 @@ def test_scan_flops_scale_with_trip_count():
     cost = analyze_hlo(comp.as_text())
     expect = L * 2 * M * M * M
     # XLA's own count misses the trip count:
-    assert cost_analysis_dict(comp)["flops"] < 0.2 * expect
+    assert comp.cost_analysis()["flops"] < 0.2 * expect
     assert abs(cost.flops - expect) / expect < 0.05
 
 
@@ -87,3 +86,21 @@ def test_dot_general_batched():
     cost = analyze_hlo(comp.as_text())
     expect = B * 2 * M * K * N
     assert abs(cost.flops - expect) / expect < 0.05
+
+
+def test_peaks_keyed_by_device_kind():
+    """Peaks come from one table keyed by device_kind; an unknown kind
+    (the CPU included) is an error, never a default."""
+    import pytest
+    from repro.roofline.analysis import analyze, peaks
+
+    v5e = peaks("TPU v5 lite")
+    assert (v5e.flops, v5e.hbm_bw) == (197e12, 819e9)
+    comp = _compile(lambda x: x * 2 + 1,
+                    jax.ShapeDtypeStruct((1024,), jnp.float32))
+    terms = analyze(comp, chips=1, model_flops=0.0, device_kind="TPU v5 lite")
+    assert terms.memory_s == terms.bytes_per_device / 819e9 > 0
+    assert terms.to_dict()["device_kind"] == "TPU v5 lite"
+    with pytest.raises(ValueError, match="no published peaks"):
+        analyze(comp, chips=1, model_flops=0.0,
+                device_kind=jax.devices()[0].device_kind)
