@@ -1,0 +1,10 @@
+"""Executor: host milliseconds per search generation, outside the device
+call: proposal sampling, planning, staging and fetch. Per generation, its
+wall time less the ``device_call`` span of ``repro.obs``; the mean over
+the window's generations."""
+
+
+def read(run):
+    vals = [c["wall_s"] - c["device_s"] for c in run["calls"]
+            if c.get("device_s") is not None]
+    return 1e3 * sum(vals) / len(vals) if vals else None
