@@ -185,13 +185,14 @@ def _phase_a(cfg: FamConfig, p: FamParams, ns: NodeState, addr, gap, warm,
     # core prefetch misses per paper §III; here the demand stream trains).
     # Cache-independent, so it hoists above the cache ops value-identically
     # — which lets ALL of this event's cache work go to the engine at once.
-    pf_state, ctx = impls.prefetch.train(cfg, pf_pol, ns.pf, page,
-                                         block_in_page,
-                                         enable=is_fam & p.dram_prefetch)
-    bpp = dyn_blocks_per_page(bb)
-    cand_gblock, cand_valid = impls.prefetch.predict(
-        cfg, pf_pol, pf_state, page, block_in_page, ctx,
-        cfg.prefetch_degree, bpp)
+    with jax.named_scope("prefetcher"):
+        pf_state, ctx = impls.prefetch.train(
+            cfg, pf_pol, ns.pf, page, block_in_page,
+            enable=is_fam & p.dram_prefetch)
+        bpp = dyn_blocks_per_page(bb)
+        cand_gblock, cand_valid = impls.prefetch.predict(
+            cfg, pf_pol, pf_state, page, block_in_page, ctx,
+            cfg.prefetch_degree, bpp)
 
     # core (stride) prefetcher target addresses (cache-independent too)
     line = (addr >> 6).astype(jnp.int32)
@@ -209,11 +210,12 @@ def _phase_a(cfg: FamConfig, p: FamParams, ns: NodeState, addr, gap, warm,
     # the event's ENTIRE cache interaction, fused (docs/performance.md):
     # C fill inserts -> demand probe + touch -> D+CPF pure probes. The
     # demand probe is masked out entirely when DRAM-cache prefetch is off.
-    cache, hit, probe_hits = cache_step(
-        ns.cache, fill_blocks, fill_ok, gblock,
-        is_fam & p.dram_prefetch, jnp.concatenate([cand_gblock,
-                                                   cpf_gblock]),
-        eff_sets, eff_ways, policy=repl, backend=cfg.kernel_backend)
+    with jax.named_scope("cache_lookup"):
+        cache, hit, probe_hits = cache_step(
+            ns.cache, fill_blocks, fill_ok, gblock,
+            is_fam & p.dram_prefetch, jnp.concatenate([cand_gblock,
+                                                       cpf_gblock]),
+            eff_sets, eff_ways, policy=repl, backend=cfg.kernel_backend)
     cand_hit = probe_hits[:cfg.prefetch_degree]
     cpf_raw_hits = probe_hits[cfg.prefetch_degree:]
 
@@ -223,8 +225,11 @@ def _phase_a(cfg: FamConfig, p: FamParams, ns: NodeState, addr, gap, warm,
     inflight = inflight & ~cpb_hit
     demand_to_fam = is_fam & ~hit & ~inflight & ~cpb_hit
 
-    cand_inflight = jax.vmap(lambda b: pq.contains(queue, b)[0])(cand_gblock)
-    fresh = ~cand_hit & ~cand_inflight
+    with jax.named_scope("prefetcher"):
+        # in-flight dedupe of the candidates
+        cand_inflight = jax.vmap(lambda b: pq.contains(queue, b)[0])(
+            cand_gblock)
+        fresh = ~cand_hit & ~cand_inflight
     pf_valid = cand_valid & fresh & is_fam & p.dram_prefetch
     pf_blocks = cand_gblock
     # adaptation: grant tokens for the surviving candidates (the rate
@@ -303,7 +308,8 @@ def _phase_c(cfg: FamConfig, p: FamParams, ns: NodeState, req,
                               enable=req["pf_valid"][i])
         return q2
 
-    queue = jax.lax.fori_loop(0, cfg.prefetch_degree, ins, queue)
+    with jax.named_scope("cache_fill"):
+        queue = jax.lax.fori_loop(0, cfg.prefetch_degree, ins, queue)
 
     fam_miss = req["is_fam"] & ~req["hit"] & ~req["inflight"]
     # record core-prefetch fills (round-robin fill buffer) for the lines
@@ -320,8 +326,9 @@ def _phase_c(cfg: FamConfig, p: FamParams, ns: NodeState, req,
         bf = bf.at[ptr_].set(jnp.where(ok, fin[i], bf[ptr_]))
         return bl, bf, (ptr_ + ok.astype(jnp.int32)) % cfg.core_fill_entries
 
-    buf_line, buf_fin, ptr = jax.lax.fori_loop(
-        0, cfg.core_pf_degree, put, (buf_line, buf_fin, ptr))
+    with jax.named_scope("cache_fill"):
+        buf_line, buf_fin, ptr = jax.lax.fori_loop(
+            0, cfg.core_pf_degree, put, (buf_line, buf_fin, ptr))
 
     live = req["live"]
     thr = impls.adaptation.observe(
@@ -399,45 +406,54 @@ def _make_step(cfg: FamConfig, num_nodes: int,
         else:
             nodes, fam_busy = carry
             addr, gap, warm, live = inputs     # addr/gap: (N,)
-        nodes, req = jax.vmap(
-            lambda ns, a, g: _phase_a(cfg, p, ns, a, g, warm, live,
-                                      policies))(
-                nodes, addr, gap)
+        # the named scopes (docs/observability.md) only tag the ops'
+        # metadata, so a device trace reads by phase; the optimized
+        # program is the same without them
+        with jax.named_scope("phase_a"):
+            nodes, req = jax.vmap(
+                lambda ns, a, g: _phase_a(cfg, p, ns, a, g, warm, live,
+                                          policies))(
+                    nodes, addr, gap)
 
-        # finite prefetch input queue at the FAM controller: when the
-        # prefetch-class backlog exceeds the cap, CXL backpressure stops
-        # prefetch issue at the nodes (this is what makes WFQ reduce
-        # prefetches-issued in the paper's Fig. 12C). The scheduler policy
-        # owns the gate (FIFO mode: none).
-        backlog_ok = impls.scheduler.backlog_ok(p, sp, fam_busy, nodes.clock)
-        req["pf_valid"] = req["pf_valid"] & backlog_ok[:, None]
-        req["cpf_to_fam"] = req["cpf_to_fam"] & backlog_ok[:, None]
+        with jax.named_scope("sched"):
+            # finite prefetch input queue at the FAM controller: when the
+            # prefetch-class backlog exceeds the cap, CXL backpressure
+            # stops prefetch issue at the nodes (this is what makes WFQ
+            # reduce prefetches-issued in the paper's Fig. 12C). The
+            # scheduler policy owns the gate (FIFO mode: none).
+            backlog_ok = impls.scheduler.backlog_ok(p, sp, fam_busy,
+                                                    nodes.clock)
+            req["pf_valid"] = req["pf_valid"] & backlog_ok[:, None]
+            req["cpf_to_fam"] = req["cpf_to_fam"] & backlog_ok[:, None]
 
-        d_arr = nodes.clock
-        d_valid = req["demand_to_fam"]
-        d_bytes = jnp.full((num_nodes,), p.demand_bytes, jnp.float32)
-        p_arr = jnp.concatenate([
-            jnp.repeat(nodes.clock, D), jnp.repeat(nodes.clock, CPF)])
-        p_valid = jnp.concatenate([req["pf_valid"].reshape(-1),
-                                   req["cpf_to_fam"].reshape(-1)])
-        p_bytes = jnp.concatenate([
-            jnp.full((num_nodes * D,), p.block_bytes, jnp.float32),
-            jnp.full((num_nodes * CPF,), p.demand_bytes,
-                     jnp.float32)])
-        t = impls.scheduler.arbitrate(p, sp, fam_busy, d_arr, d_valid,
-                                      d_bytes, p_arr, p_valid, p_bytes)
-        pf_fin = t.prefetch_finish[: num_nodes * D].reshape(num_nodes, D)
-        cpf_fin = t.prefetch_finish[num_nodes * D:].reshape(
-            num_nodes, CPF)
+            d_arr = nodes.clock
+            d_valid = req["demand_to_fam"]
+            d_bytes = jnp.full((num_nodes,), p.demand_bytes, jnp.float32)
+            p_arr = jnp.concatenate([
+                jnp.repeat(nodes.clock, D), jnp.repeat(nodes.clock, CPF)])
+            p_valid = jnp.concatenate([req["pf_valid"].reshape(-1),
+                                       req["cpf_to_fam"].reshape(-1)])
+            p_bytes = jnp.concatenate([
+                jnp.full((num_nodes * D,), p.block_bytes, jnp.float32),
+                jnp.full((num_nodes * CPF,), p.demand_bytes,
+                         jnp.float32)])
+            t = impls.scheduler.arbitrate(p, sp, fam_busy, d_arr, d_valid,
+                                          d_bytes, p_arr, p_valid, p_bytes)
+            pf_fin = t.prefetch_finish[: num_nodes * D].reshape(
+                num_nodes, D)
+            cpf_fin = t.prefetch_finish[num_nodes * D:].reshape(
+                num_nodes, CPF)
 
-        nodes, lat = jax.vmap(
-            lambda ns, r, df, pf, cf: _phase_c(cfg, p, ns, r, df, pf, cf,
-                                               policies)
-        )(nodes, req, t.demand_finish, pf_fin, cpf_fin)
+        with jax.named_scope("phase_c"):
+            nodes, lat = jax.vmap(
+                lambda ns, r, df, pf, cf: _phase_c(cfg, p, ns, r, df, pf,
+                                                   cf, policies)
+            )(nodes, req, t.demand_finish, pf_fin, cpf_fin)
         if n_win:
-            tele = obs_telemetry.accumulate(
-                tele, win, num_nodes=num_nodes, live=live, req=req,
-                lat=lat, nodes=nodes, new_busy=t.new_busy)
+            with jax.named_scope("telemetry"):
+                tele = obs_telemetry.accumulate(
+                    tele, win, num_nodes=num_nodes, live=live, req=req,
+                    lat=lat, nodes=nodes, new_busy=t.new_busy)
             return (nodes, t.new_busy, tele), None
         return (nodes, t.new_busy), None
 
@@ -457,25 +473,29 @@ def _init_carry(cfg: FamConfig, p: FamParams, num_nodes: int,
 def _metrics(nodes: NodeState, p: FamParams,
              telemetry: Optional[jax.Array] = None
              ) -> Dict[str, jax.Array]:
-    ipc = nodes.instr / jnp.maximum(nodes.cycles, 1.0)
-    out = {
-        "ipc": ipc,
-        "fam_latency": nodes.fam_lat_sum / jnp.maximum(nodes.fam_cnt, 1.0),
-        "demand_hit_fraction": nodes.demand_hit /
-            jnp.maximum(nodes.demand_fam, 1.0),
-        "corepf_hit_fraction": nodes.corepf_hit /
-            jnp.maximum(nodes.corepf_fam, 1.0),
-        "prefetches_issued": nodes.pf_issued,
-        "issue_rate": nodes.throttle.issue_rate,
-        # occupancy over the EFFECTIVE geometry (padded region stays empty)
-        "cache_occupancy": jax.vmap(
-            lambda c: dc.occupancy(c, p.num_sets, p.cache_ways))(nodes.cache),
-    }
-    if telemetry is not None:
-        # windowed observability streams (repro.obs.telemetry): one
-        # per-system (node-summed) ``(n_windows, N_COUNTERS)`` matrix
-        out["telemetry"] = telemetry
-    return out
+    with jax.named_scope("metrics"):
+        ipc = nodes.instr / jnp.maximum(nodes.cycles, 1.0)
+        out = {
+            "ipc": ipc,
+            "fam_latency": nodes.fam_lat_sum /
+                jnp.maximum(nodes.fam_cnt, 1.0),
+            "demand_hit_fraction": nodes.demand_hit /
+                jnp.maximum(nodes.demand_fam, 1.0),
+            "corepf_hit_fraction": nodes.corepf_hit /
+                jnp.maximum(nodes.corepf_fam, 1.0),
+            "prefetches_issued": nodes.pf_issued,
+            "issue_rate": nodes.throttle.issue_rate,
+            # occupancy over the EFFECTIVE geometry (padded region stays
+            # empty)
+            "cache_occupancy": jax.vmap(
+                lambda c: dc.occupancy(c, p.num_sets, p.cache_ways))(
+                    nodes.cache),
+        }
+        if telemetry is not None:
+            # windowed observability streams (repro.obs.telemetry): one
+            # per-system (node-summed) ``(n_windows, N_COUNTERS)`` matrix
+            out["telemetry"] = telemetry
+        return out
 
 
 def _make_run(cfg: FamConfig, num_nodes: int, warmup_frac: float = 0.2,
@@ -574,7 +594,8 @@ def _make_run_masked(cfg: FamConfig, num_nodes: int,
         return _sim
 
     def run_gen(p: FamParams, trace_params, t_true, warm_start):
-        addrs, gaps = jax.vmap(trace_gen)(trace_params)   # (N, T_pad)
+        with jax.named_scope("trace_gen"):
+            addrs, gaps = jax.vmap(trace_gen)(trace_params)   # (N, T_pad)
         return _sim(p, addrs, gaps, t_true, warm_start)
 
     return run_gen

@@ -229,26 +229,33 @@ def _prepare(points: Sequence[ResolvedPoint], idxs: Sequence[int],
     N = len(pts[0].workloads)
     S = len(pts)
     host_events = 0
-    if trace_backend == "device":
-        from repro.traces.device import stack_system_params, system_params
-        tp = stack_system_params(
-            [system_params(pt.workloads, pt.seed) for pt in pts])
-        inputs = (tp,)
-    else:
-        addrs = np.zeros((S, N, t_pad), np.int32)
-        gaps = np.zeros((S, N, t_pad), np.float32)
-        for j, pt in enumerate(pts):
-            # count events actually GENERATED host-side (memoized reuse
-            # is free — repeated points and inert padded lanes cost 0)
-            host_events += sum(
-                pt.T for i, w in enumerate(pt.workloads)
-                if (w, pt.T, node_seed(pt.seed, i)) not in _TRACE_CACHE)
-            a, g = trace_arrays(pt.workloads, pt.T, pt.seed)
-            addrs[j, :, :pt.T] = a
-            gaps[j, :, :pt.T] = g
-        inputs = (addrs, gaps)
-    params = stack_params([FamParams.of(pt.cfg, pt.flags, pt.policy_set())
-                           for pt in pts])
+    # one span per staging phase, over all S systems (docs/observability.md)
+    with maybe_span("stage.traces"):
+        if trace_backend == "device":
+            from repro.traces.device import (stack_system_params,
+                                             system_params)
+            tp = stack_system_params(
+                [system_params(pt.workloads, pt.seed) for pt in pts])
+            inputs = (tp,)
+        else:
+            addrs = np.zeros((S, N, t_pad), np.int32)
+            gaps = np.zeros((S, N, t_pad), np.float32)
+            for j, pt in enumerate(pts):
+                # count events actually GENERATED host-side (memoized
+                # reuse is free — repeated points and inert padded lanes
+                # cost 0)
+                host_events += sum(
+                    pt.T for i, w in enumerate(pt.workloads)
+                    if (w, pt.T, node_seed(pt.seed, i)) not in _TRACE_CACHE)
+                a, g = trace_arrays(pt.workloads, pt.T, pt.seed)
+                addrs[j, :, :pt.T] = a
+                gaps[j, :, :pt.T] = g
+            inputs = (addrs, gaps)
+    with maybe_span("stage.params"):
+        per_system = [FamParams.of(pt.cfg, pt.flags, pt.policy_set())
+                      for pt in pts]
+    with maybe_span("stage.stack"):
+        params = stack_params(per_system)
     # ``pt.t_true`` == pt.T unless the point is lifetime-gated (t_live,
     # e.g. an admission-throttled tenant): the traced masked-runner input
     # no-ops the non-live tail, never the compile key

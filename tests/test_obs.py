@@ -278,6 +278,65 @@ def test_executor_records_spans_per_group():
     assert info2.spans is None and "spans" not in info2.as_dict()
 
 
+def test_span_tracer_keeps_absolute_times_and_calls_listeners():
+    import threading
+    import time
+
+    tracer = SpanTracer()
+    heard = []
+    tracer.listeners.append(lambda *a: heard.append(a))
+    t_before = time.perf_counter()
+    with tracer.span("outer"):
+        with tracer.span("inner"):
+            pass
+        th = threading.Thread(target=_span_in, args=(tracer, "worker"))
+        th.start()
+        th.join(timeout=10)
+        assert not th.is_alive()
+    t_after = time.perf_counter()
+    by = {n: (s, e) for n, s, e in tracer.spans}
+    assert [n for n, _, _ in tracer.spans] == ["inner", "worker", "outer"]
+    assert t_before <= by["outer"][0] <= by["inner"][0] <= by["inner"][1] \
+        <= by["worker"][0] <= by["worker"][1] <= by["outer"][1] <= t_after
+    assert [(n, w) for n, w, _ in heard] == [
+        ("outer", "start"), ("inner", "start"), ("inner", "end"),
+        ("worker", "start"), ("worker", "end"), ("outer", "end")]
+    assert [t for _, _, t in heard] == [
+        by["outer"][0], by["inner"][0], by["inner"][1], by["worker"][0],
+        by["worker"][1], by["outer"][1]]
+    # the Chrome events are the same spans, relative to the tracer's start
+    ev = {e["name"]: e for e in tracer.chrome_trace()["traceEvents"]}
+    assert ev["inner"]["ts"] == pytest.approx(
+        (by["inner"][0] - tracer._t0) * 1e6, abs=0.1)
+    assert ev["worker"]["tid"] != ev["outer"]["tid"]
+
+
+def _span_in(tracer, name):
+    with tracer.span(name):
+        pass
+
+
+def test_executor_stages_in_three_spans_inside_trace_stage():
+    """``trace_stage`` holds one span per staging phase: trace encodings,
+    the per-system params, their stacking."""
+    exp = Experiment(name="obs_stage_spans", T=600,
+                     axes=(workload_axis(WL),))
+    tracer = SpanTracer()
+    prev = set_tracer(tracer)
+    try:
+        info = exp.run().info
+    finally:
+        set_tracer(prev)
+    stages = [s for s in tracer.spans if s[0] == "trace_stage"]
+    assert len(stages) == info.planned_groups >= 1
+    for name in ("stage.traces", "stage.params", "stage.stack"):
+        inside = [(s, e) for n, s, e in tracer.spans if n == name]
+        assert len(inside) == len(stages), name
+        for s, e in inside:
+            assert any(a <= s <= e <= b for _, a, b in stages), name
+    assert validate_trace_events(tracer.chrome_trace()) == []
+
+
 def test_run_info_us_per_call_zero_event_guard():
     from repro.experiments.executor import RunInfo
     info = RunInfo(planned_groups=0, run_s=1.0)
