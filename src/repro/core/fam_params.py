@@ -36,6 +36,7 @@ from typing import Any, Dict, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import FamConfig
 from repro.core.addresses import block_bits
@@ -43,8 +44,11 @@ from repro.policies import PolicySet, SimFlags
 
 
 class FamParams(NamedTuple):
-    """Per-system dynamic scalars. Leaves are jnp scalars — or, after
-    :func:`stack_params`, arrays with a leading sweep axis for ``vmap``."""
+    """Per-system dynamic scalars. :meth:`of` builds the leaves as host
+    numpy scalars; :func:`stack_params` stacks them into host arrays with
+    a leading sweep axis for ``vmap``, which the executor sends to the
+    device in one transfer. Under ``jit`` a numpy leaf and a device
+    scalar of the same dtype are the same traced argument."""
 
     # core / memory timing
     base_ipc: jax.Array
@@ -88,9 +92,7 @@ class FamParams(NamedTuple):
         ``flags.wfq``/``flags.wfq_weight`` are ignored then — while the
         remaining flag booleans always populate the dynamic feature gates.
         """
-        f32 = lambda v: jnp.float32(v)
-        i32 = lambda v: jnp.int32(v)
-        b = lambda v: jnp.bool_(v)
+        f32, i32, b = np.float32, np.int32, np.bool_
         if flags is None:
             flags = SimFlags()
         if policies is None:
@@ -145,9 +147,10 @@ class FamParams(NamedTuple):
 
 
 def stack_params(params: Sequence[FamParams]) -> FamParams:
-    """Stack S per-system FamParams into one batch with leading axis S.
+    """Stack S per-system FamParams into one host batch with leading axis
+    S (numpy arrays, dtypes kept).
 
     Every member must share the policy-param schema — i.e. come from
     PolicySets with equal compile tags (the planner's group invariant).
     """
-    return jax.tree.map(lambda *xs: jnp.stack(xs), *params)
+    return jax.tree.map(lambda *xs: np.stack(xs), *params)

@@ -224,6 +224,7 @@ class _GroupData:
 def _prepare(points: Sequence[ResolvedPoint], idxs: Sequence[int],
              t_pad: int, warmup_frac: float,
              trace_backend: str = "numpy") -> _GroupData:
+    import jax
     t0 = time.perf_counter()
     pts = [points[i] for i in idxs]
     N = len(pts[0].workloads)
@@ -255,7 +256,9 @@ def _prepare(points: Sequence[ResolvedPoint], idxs: Sequence[int],
         per_system = [FamParams.of(pt.cfg, pt.flags, pt.policy_set())
                       for pt in pts]
     with maybe_span("stage.stack"):
-        params = stack_params(per_system)
+        # host numpy stack, then ONE transfer of the whole pytree (left
+        # uncommitted, so the shard_map path lays it out as it needs)
+        params = jax.device_put(stack_params(per_system))
     # ``pt.t_true`` == pt.T unless the point is lifetime-gated (t_live,
     # e.g. an admission-throttled tenant): the traced masked-runner input
     # no-ops the non-live tail, never the compile key

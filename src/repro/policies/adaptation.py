@@ -19,6 +19,7 @@ from __future__ import annotations
 from typing import NamedTuple
 
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.throttle import (init_throttle, maybe_adapt, observe,
                                  take_tokens)
@@ -45,12 +46,12 @@ class TokenBucketAdaptation:
     compile_tag = "adaptation:throttle"
 
     def params_of(self, cfg):
-        return {"sample_interval": jnp.int32(cfg.sample_interval),
+        return {"sample_interval": np.int32(cfg.sample_interval),
                 "latency_noise_threshold":
-                    jnp.float32(cfg.latency_noise_threshold),
-                "mimd_increase": jnp.float32(cfg.mimd_increase),
-                "ema_alpha": jnp.float32(cfg.ema_alpha),
-                "min_issue_rate": jnp.float32(cfg.min_issue_rate)}
+                    np.float32(cfg.latency_noise_threshold),
+                "mimd_increase": np.float32(cfg.mimd_increase),
+                "ema_alpha": np.float32(cfg.ema_alpha),
+                "min_issue_rate": np.float32(cfg.min_issue_rate)}
 
     def gate(self, p):
         """Active only under the legacy ``bw_adapt`` feature flag (the
@@ -86,7 +87,7 @@ class StaticRateAdaptation:
     compile_tag = "adaptation:static"
 
     def params_of(self, cfg):
-        return {"rate": jnp.float32(1.0)}
+        return {"rate": np.float32(1.0)}
 
     def gate(self, p):
         """Always active: choosing the static policy IS the opt-in — its

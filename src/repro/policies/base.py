@@ -48,6 +48,8 @@ from dataclasses import dataclass, replace
 from typing import (Any, Dict, Mapping, NamedTuple, Optional, Protocol,
                     Tuple, runtime_checkable)
 
+import numpy as np
+
 POLICY_KINDS = ("prefetch", "scheduler", "replacement", "adaptation")
 
 
@@ -88,7 +90,7 @@ class Policy(Protocol):
     compile_tag: str   # static identity entering the compile key
 
     def params_of(self, cfg) -> Dict[str, Any]:
-        """Declarative numeric-param pytree (name -> jnp scalar), sourced
+        """Declarative numeric-param pytree (name -> numpy scalar), sourced
         from ``FamConfig`` defaults; every leaf is traced at run time."""
         ...
 
@@ -252,10 +254,9 @@ class PolicySet:
 
     def numeric_params(self, cfg) -> Dict[str, Dict[str, Any]]:
         """The per-policy traced-scalar pytree carried on
-        ``FamParams.policy``: ``{kind: {param: jnp scalar}}``, defaults
+        ``FamParams.policy``: ``{kind: {param: numpy scalar}}``, defaults
         from each policy's ``params_of(cfg)`` with ``overrides`` applied
         (cast to the default leaf's dtype)."""
-        import jax.numpy as jnp
         ov = dict((k, dict(v)) for k, v in self.overrides)
         out: Dict[str, Dict[str, Any]] = {}
         for kind in POLICY_KINDS:
@@ -266,7 +267,7 @@ class PolicySet:
                         f"{kind} policy {getattr(self, kind)!r} has no "
                         f"numeric param {name!r}; schema: "
                         f"{sorted(params)}")
-                params[name] = jnp.asarray(value, params[name].dtype)
+                params[name] = np.asarray(value, params[name].dtype)
             out[kind] = params
         if ov:
             raise ValueError(f"overrides for unknown policy kinds: "

@@ -20,6 +20,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import spp as spp_lib
 from repro.policies.base import register
@@ -35,7 +36,7 @@ class SppPrefetch:
 
     def params_of(self, cfg):
         return {"confidence_threshold":
-                jnp.float32(cfg.spp_confidence_threshold)}
+                np.float32(cfg.spp_confidence_threshold)}
 
     def init(self, cfg):
         return spp_lib.init_spp(cfg)
@@ -58,7 +59,7 @@ class NextLinePrefetch:
     compile_tag = "prefetch:nextline"
 
     def params_of(self, cfg):
-        return {"distance": jnp.float32(1.0)}
+        return {"distance": np.float32(1.0)}
 
     def init(self, cfg):
         return jnp.int32(0)          # stateless (scan-carry placeholder)
@@ -101,8 +102,8 @@ class BestOffsetPrefetch:
     compile_tag = "prefetch:bestoffset"
 
     def params_of(self, cfg):
-        return {"round_len": jnp.float32(64.0),
-                "score_threshold": jnp.float32(8.0)}
+        return {"round_len": np.float32(64.0),
+                "score_threshold": np.float32(8.0)}
 
     def init(self, cfg):
         K = len(BO_OFFSETS)

@@ -16,6 +16,7 @@
 from __future__ import annotations
 
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.fam_controller import FamTimings, arbitrate, service_chain
 from repro.policies.base import register
@@ -38,9 +39,9 @@ class ChainScheduler:
         self._use_wfq = use_wfq
 
     def params_of(self, cfg):
-        return {"use_wfq": jnp.bool_(self._use_wfq),
-                "weight": jnp.float32(cfg.wfq_weight),
-                "backlog_cap": jnp.float32(cfg.wfq_backlog_cap)}
+        return {"use_wfq": np.bool_(self._use_wfq),
+                "weight": np.float32(cfg.wfq_weight),
+                "backlog_cap": np.float32(cfg.wfq_backlog_cap)}
 
     def backlog_ok(self, p, pol, fam_busy, clock):
         # finite prefetch input queue at the controller: CXL backpressure
@@ -72,7 +73,7 @@ class StrictScheduler:
     compile_tag = "scheduler:strict"
 
     def params_of(self, cfg):
-        return {"backlog_cap": jnp.float32(cfg.wfq_backlog_cap)}
+        return {"backlog_cap": np.float32(cfg.wfq_backlog_cap)}
 
     def backlog_ok(self, p, pol, fam_busy, clock):
         return (fam_busy[1] - clock) < pol["backlog_cap"]

@@ -210,6 +210,55 @@ def test_run_plan_dry_run(capsys):
 # executor
 # ---------------------------------------------------------------------------
 
+def _staged_small_group(monkeypatch, backend):
+    """Stage the small experiment's one group, recording every
+    ``jax.device_put`` made while staging."""
+    from repro.experiments import executor as ex
+    plan = _small_experiment().plan()
+    (g,) = plan.groups
+    idxs = ex._pad_systems(g.indices, g.s_pad, 1)
+    puts = []
+    real_put = jax.device_put
+
+    def counting_put(x, *args, **kw):
+        puts.append(x)
+        return real_put(x, *args, **kw)
+
+    monkeypatch.setattr(jax, "device_put", counting_put)
+    data = ex._prepare(plan.points, idxs, g.t_pad, 0.2, backend)
+    return plan, g, idxs, data, puts
+
+
+@pytest.mark.parametrize("backend", ["device", "numpy"])
+def test_prepare_params_match_group_signature(monkeypatch, backend):
+    """Staged params arrive as uncommitted device arrays with leading
+    axis S and exactly the dtypes the group executable is compiled for."""
+    from repro.experiments.executor import group_program
+    plan, g, idxs, data, _ = _staged_small_group(monkeypatch, backend)
+    rep = plan.points[g.indices[0]]
+    _, (want, *_inputs) = group_program(
+        rep.cfg, len(idxs), g.key.num_nodes, g.t_pad, pad_sets=g.pad_sets,
+        pad_ways=g.pad_ways, trace_backend=backend,
+        policies=rep.policy_set())
+    assert jax.tree.structure(data.params) == jax.tree.structure(want)
+    for leaf, spec in zip(jax.tree.leaves(data.params),
+                          jax.tree.leaves(want)):
+        assert isinstance(leaf, jax.Array) and not leaf.committed
+        assert leaf.shape == spec.shape and leaf.shape[0] == len(idxs)
+        assert leaf.dtype == spec.dtype
+
+
+def test_prepare_sends_params_in_one_transfer(monkeypatch):
+    """The group's params are stacked on the host and sent in ONE
+    ``jax.device_put`` — not one dispatch per scalar and per leaf."""
+    _, _, idxs, data, puts = _staged_small_group(monkeypatch, "device")
+    assert len(puts) == 1
+    (sent,) = puts
+    assert jax.tree.structure(sent) == jax.tree.structure(data.params)
+    assert all(isinstance(x, np.ndarray) and x.shape[0] == len(idxs)
+               for x in jax.tree.leaves(sent))
+
+
 def test_padded_executor_matches_unpadded_per_point(small_result):
     """The masked executor must reproduce the classic build_sim run
     bit-exactly — both for a uniform-T group (executed at exact T) and for

@@ -125,6 +125,71 @@ def test_famparams_carries_policy_pytree():
         np.asarray(flipped.policy["scheduler"]["weight"]), [1.0, 1.0])
 
 
+#: members of one compile group each (equal policy compile tags), mixing
+#: what the FamParams leaves carry: block sizes, feature flags, a WFQ
+#: weight, numeric-param overrides, and floats float32 must round
+_PARAM_GROUPS = {
+    "block_sizes": [(fam_replace(CFG, block_bytes=b), DRAM, None)
+                    for b in (64, 256, 4096)],
+    "flags": [(CFG, fl, None) for fl in (
+        SimFlags(core_prefetch=False, dram_prefetch=False),
+        SimFlags(bw_adapt=True), SimFlags(all_local=True))],
+    "wfq_weight": [(CFG, SimFlags(wfq=True, wfq_weight=w), None)
+                   for w in (1, 3, 0.7)],
+    "override": [
+        (CFG, DRAM, PolicySet().override("prefetch",
+                                         confidence_threshold=0.37)),
+        (CFG, DRAM, PolicySet().override("adaptation", ema_alpha=0.1,
+                                         sample_interval=300))],
+    "mixed": [
+        (fam_replace(CFG, block_bytes=128, mimd_increase=1.1),
+         SimFlags(wfq=True, wfq_weight=2.5, bw_adapt=True), None),
+        (CFG, SimFlags(core_prefetch=False),
+         PolicySet(scheduler="wfq").override("scheduler", weight=0.3)),
+        (fam_replace(CFG, block_bytes=4096), DRAM,
+         PolicySet().override("prefetch", confidence_threshold=0.9))],
+}
+
+
+def _jnp_stacked(monkeypatch, members):
+    """Reference: the same members built from eager ``jnp`` scalars (the
+    constructors the leaves had before they moved to the host) and
+    stacked leaf by leaf with ``jnp.stack``."""
+    import types
+
+    from repro.core import fam_params
+    from repro.policies import adaptation, base, prefetch, scheduler
+    as_jnp = types.SimpleNamespace(float32=jnp.float32, int32=jnp.int32,
+                                   bool_=jnp.bool_, asarray=jnp.asarray)
+    with monkeypatch.context() as m:
+        for mod in (fam_params, adaptation, base, prefetch, scheduler):
+            m.setattr(mod, "np", as_jnp)
+        per = [FamParams.of(c, fl, pol) for c, fl, pol in members]
+    assert all(isinstance(x, jax.Array)
+               for p in per for x in jax.tree.leaves(p))
+    return jax.tree.map(lambda *xs: jnp.stack(xs), *per)
+
+
+@pytest.mark.parametrize("group", sorted(_PARAM_GROUPS))
+def test_host_params_bit_identical_to_jnp_scalars(monkeypatch, group):
+    """FamParams.of builds host numpy leaves (no device dispatch per
+    scalar), and stack_params of them equals a jnp-scalar stack bit for
+    bit and in dtype."""
+    members = _PARAM_GROUPS[group]
+    per = [FamParams.of(c, fl, pol) for c, fl, pol in members]
+    for p in per:
+        for leaf in jax.tree.leaves(p):
+            assert isinstance(leaf, (np.generic, np.ndarray)), type(leaf)
+            assert not isinstance(leaf, jax.Array)
+    got = stack_params(per)
+    ref = _jnp_stacked(monkeypatch, members)
+    assert jax.tree.structure(got) == jax.tree.structure(ref)
+    for g, r in zip(jax.tree.leaves(got), jax.tree.leaves(ref)):
+        assert isinstance(g, np.ndarray) and g.shape == (len(members),)
+        assert g.dtype == r.dtype
+        assert g.tobytes() == np.asarray(r).tobytes()
+
+
 def test_hoisted_core_constants_in_static_key():
     """The former famsim module constants are FamConfig shape fields now
     and participate in the compile key (defaults unchanged)."""
