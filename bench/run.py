@@ -6,7 +6,9 @@ A cell (an entry of ``workloads`` in ``BENCHMARK.json``) names a
 configuration (``bench/configs/``) and a traffic file (``bench/traffic/``).
 The run drives the program's experiment path in a closed loop: each call
 expands the traffic file into a grid of simulated systems with new traces
-(:mod:`grid`), plans it and executes it with ``repro.experiments.execute``.
+(:func:`grid.expansion`: :mod:`grid`'s own generator, or the module the
+traffic file names under ``expand``), plans it and executes it with
+``repro.experiments.execute``.
 
 * Set-up: process start, the compile of the cell's one group from the
   persistent compilation cache (``JAX_COMPILATION_CACHE_DIR``, else
@@ -15,11 +17,14 @@ expands the traffic file into a grid of simulated systems with new traces
   taken over the span of the calls made. XLA compiles inside the window
   are counted (there should be none).
 * ``--trace 1``: ``repro.obs`` spans are recorded around every layer and a
-  ``jax.profiler`` trace is taken of one call boundary of the window; the
-  per-layer metrics are read by the files in ``bench/metrics/``.
+  ``jax.profiler`` trace (host spans and device ops, no Python tracer) is
+  taken of one call boundary of the window; the per-layer metrics are read
+  by the files in ``bench/metrics/``.
 * Afterwards a sample of the window's systems is run through the plain
-  reference (:mod:`reference`) and each compared number is held to its
-  limit in ``bench/limits/<cell>.json`` (:mod:`check`).
+  reference that the configuration names under ``reference`` (a module in
+  ``bench/``: ``METRICS`` and ``simulate(system, dtype)``) and each
+  compared number is held to its limit in ``bench/limits/<cell>.json``
+  (:mod:`check`).
 
 Fails, with no result line, unless JAX finds a TPU of a kind listed in
 ``bench/chips.json`` and as many chips as the cell asks for. The last line
@@ -31,7 +36,6 @@ _T0 = time.perf_counter()
 
 import argparse  # noqa: E402
 import glob  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
@@ -90,12 +94,13 @@ def load_cell(name: str) -> dict:
 
 def reader(metric: str):
     """The ``read(run)`` function of ``bench/metrics/<metric>.py``."""
-    path = BENCH / "metrics" / f"{metric}.py"
-    spec = importlib.util.spec_from_file_location(
-        "metric_" + metric.replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return grid.bench_module(f"metrics/{metric}.py").read
+
+
+def reference(config: dict):
+    """The plain reference module the configuration names (``reference``:
+    a file in ``bench/``)."""
+    return grid.bench_module(config["reference"])
 
 
 def chip_facts(devices, chips: int, require_tpu: bool) -> dict:
@@ -195,7 +200,11 @@ class BoundaryTrace:
                 return
             t = self.starts[k] + max(self.expect - self.ctx, 0.0)
             self._wait(lambda: k in self.ends, until=t)
-            jax.profiler.start_trace(self.logdir)
+            # no Python tracer: it slows the host work in the slice, where
+            # the idle gaps and host_ms are read
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            jax.profiler.start_trace(self.logdir, profiler_options=opts)
             self.t_begin = time.perf_counter()
             with jax.profiler.TraceAnnotation("bench_slice_begin"):
                 pass
@@ -264,16 +273,16 @@ def run(args, require_tpu: bool = True) -> dict:
     counter = CompileCounter()
     jax.monitoring.register_event_duration_secs_listener(counter)
     seed = int(args.seed)
+    expand_systems, to_experiment = grid.expansion(traffic)
 
     def one_call(i: int) -> dict:
         sp = tracer.span if tracer is not None else \
             (lambda name: nullcontext())
         t0 = time.perf_counter()
         with sp("proposals"):
-            systems = grid.systems(traffic, config, seed, i)
+            systems = expand_systems(traffic, config, seed, i)
         with sp("plan"):
-            plan = grid.to_experiment(systems, config,
-                                      cell["name"]).plan()
+            plan = to_experiment(systems, config, cell["name"]).plan()
         res = execute(plan, devices=chips,
                       warmup_frac=traffic["warmup_frac"])
         t1 = time.perf_counter()
@@ -337,13 +346,13 @@ def run(args, require_tpu: bool = True) -> dict:
 
     # the output check: a sample of the window's systems, after the window
     t_ref = time.perf_counter()
-    import reference
+    ref = reference(config)
     chk = traffic["check"]
     picked = check.sample(seed, [(c["index"], c["systems"]) for c in calls],
                           chk["points"], chk["stratify"])
     by_index = {c["index"]: c for c in calls}
     prog = [by_index[c]["metrics"][i] for c, i in picked]
-    refs = [reference.simulate(by_index[c]["systems"][i]) for c, i in picked]
+    refs = [ref.simulate(by_index[c]["systems"][i]) for c, i in picked]
     numbers = check.gaps(prog, refs, list(spec["limits"]["numbers"]))
     ok, table = check.judge(numbers, spec["limits"]["numbers"])
     say(f"reference: {len(picked)} systems in "

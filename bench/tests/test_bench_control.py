@@ -1,5 +1,6 @@
-"""The control: the reference computed in bfloat16, the precision below the
-configurations' float32, put in the program's place, fails the check."""
+"""The control: each configuration's reference computed in bfloat16, the
+precision below the configurations' float32, put in the program's place,
+fails the check."""
 import json
 from pathlib import Path
 
@@ -16,11 +17,12 @@ def test_bfloat16_reference_fails_the_check(cell):
 
     import check
     import grid
-    import reference
     import run
     spec = run.load_cell(cell)
+    reference = run.reference(spec["config"])
     traffic = dict(spec["traffic"], T=400)
-    systems = grid.systems(traffic, spec["config"], 2**31 + 99, 1)
+    expand_systems, _ = grid.expansion(traffic)
+    systems = expand_systems(traffic, spec["config"], 2**31 + 99, 1)
     chk = traffic["check"]
     picked = check.sample(5, [(1, systems)], 3, chk["stratify"])
     chosen = [systems[i] for _, i in picked]

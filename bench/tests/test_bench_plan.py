@@ -1,5 +1,6 @@
-"""Each traffic file plans into exactly one compile group at the stated
-S, N, T and cache padding."""
+"""Each traffic file, through its expansion (``grid``'s own or its
+``expand`` module), plans into exactly one compile group at the stated S,
+N, T and cache padding."""
 import json
 from pathlib import Path
 
@@ -17,9 +18,10 @@ def test_traffic_plans_one_group(cell):
     spec = run.load_cell(cell)
     traffic, config = spec["traffic"], spec["config"]
     want = traffic["expect"]
+    expand_systems, to_experiment = grid.expansion(traffic)
     for call in (0, 1):
-        systems = grid.systems(traffic, config, 2**31 + 7, call)
-        plan = grid.to_experiment(systems, config, cell).plan()
+        systems = expand_systems(traffic, config, 2**31 + 7, call)
+        plan = to_experiment(systems, config, cell).plan()
         assert plan.num_groups == want["groups"] == 1
         (g,) = plan.groups
         assert len(systems) == g.size == want["systems"]
