@@ -160,13 +160,14 @@ def _phase_a(cfg: FamConfig, p: FamParams, ns: NodeState, addr, gap, warm,
     # be gathered up front (value-identical to reading them inside the
     # fill loop) and the queue drained with one scatter — the sequential
     # part (same-set fills interact) lives in the cache engine.
-    done = (ns.queue.block > 0) & (ns.queue.finish <= clock) & live
-    score = jnp.where(done, -ns.queue.finish, -jnp.inf)
-    _, idxs = jax.lax.top_k(score, cfg.completions_per_step)
-    fill_blocks = ns.queue.block[idxs] - 1
-    fill_ok = done[idxs] & (ns.queue.block[idxs] > 0)
-    queue = ns.queue._replace(block=ns.queue.block.at[idxs].set(
-        jnp.where(fill_ok, 0, ns.queue.block[idxs])))
+    with jax.named_scope("prefetch_queue"):
+        done = (ns.queue.block > 0) & (ns.queue.finish <= clock) & live
+        score = jnp.where(done, -ns.queue.finish, -jnp.inf)
+        _, idxs = jax.lax.top_k(score, cfg.completions_per_step)
+        fill_blocks = ns.queue.block[idxs] - 1
+        fill_ok = done[idxs] & (ns.queue.block[idxs] > 0)
+        queue = ns.queue._replace(block=ns.queue.block.at[idxs].set(
+            jnp.where(fill_ok, 0, ns.queue.block[idxs])))
 
     page, block_in_page = dyn_split(addr, bb)
     page = page.astype(jnp.int32)
@@ -242,7 +243,8 @@ def _phase_a(cfg: FamConfig, p: FamParams, ns: NodeState, addr, gap, warm,
     rank = jnp.cumsum(pf_valid.astype(jnp.int32))
     pf_valid = pf_valid & (rank <= grant)
     # queue-space gate (§III-A2: drop when the queue is full/threshold)
-    free = jnp.sum((queue.block == 0).astype(jnp.int32))
+    with jax.named_scope("prefetch_queue"):
+        free = jnp.sum((queue.block == 0).astype(jnp.int32))
     pf_valid = pf_valid & (jnp.cumsum(pf_valid.astype(jnp.int32)) <= free)
 
     # core prefetches may hit the DRAM cache (probed by the engine above)

@@ -3,6 +3,11 @@
 returns; demand requests probe it to detect in-flight prefetches; when full,
 no further prefetches issue (static rate limiting — the BW-adaptive throttle
 composes on top, §IV-B).
+
+The probe and the insert run under the in-graph scope ``prefetch_queue``
+(docs/observability.md), nested in the scope of their caller, as does the
+simulator's drain (``famsim._phase_a``); the scope tags the ops' metadata
+only.
 """
 from __future__ import annotations
 
@@ -28,9 +33,10 @@ def occupancy(q: PrefetchQueue) -> jax.Array:
 
 def contains(q: PrefetchQueue, block_addr) -> Tuple[jax.Array, jax.Array]:
     """-> (in_flight, finish_time). Demand probe (MSHR-style hit)."""
-    match = q.block == (block_addr.astype(jnp.int32) + 1)
-    inflight = jnp.any(match)
-    finish = jnp.max(jnp.where(match, q.finish, 0.0))
+    with jax.named_scope("prefetch_queue"):
+        match = q.block == (block_addr.astype(jnp.int32) + 1)
+        inflight = jnp.any(match)
+        finish = jnp.max(jnp.where(match, q.finish, 0.0))
     return inflight, finish
 
 
@@ -43,13 +49,16 @@ def try_insert(q: PrefetchQueue, block_addr, finish_time,
     e.g. 95%.) Returns (queue, inserted?). ``enable`` masks the write.
     """
     size = q.block.shape[0]
-    free = q.block == 0
-    ok = jnp.any(free) & (occupancy(q) < jnp.int32(threshold * size)) &         jnp.asarray(enable)
-    slot = jnp.argmax(free)
-    blk = block_addr.astype(jnp.int32) + 1
-    q2 = PrefetchQueue(
-        block=q.block.at[slot].set(jnp.where(ok, blk, q.block[slot])),
-        finish=q.finish.at[slot].set(jnp.where(ok, finish_time, q.finish[slot])))
+    with jax.named_scope("prefetch_queue"):
+        free = q.block == 0
+        ok = (jnp.any(free) & (occupancy(q) < jnp.int32(threshold * size))
+              & jnp.asarray(enable))
+        slot = jnp.argmax(free)
+        blk = block_addr.astype(jnp.int32) + 1
+        q2 = PrefetchQueue(
+            block=q.block.at[slot].set(jnp.where(ok, blk, q.block[slot])),
+            finish=q.finish.at[slot].set(
+                jnp.where(ok, finish_time, q.finish[slot])))
     return q2, ok
 
 

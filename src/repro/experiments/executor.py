@@ -34,10 +34,13 @@ in seed simulate different traces.
 Compile time is measured separately from steady-state run time
 (``jit(...).lower(...).compile()`` + ``block_until_ready``) and recorded
 per group, so ``us_per_event`` reflects simulation only;
-``RunInfo.trace_gen_s`` records the host-side trace/param staging time.
+``RunInfo.trace_gen_s`` records the host-side trace/param staging time,
+and ``RunInfo.place_s`` the part of it that places the inputs on the mesh
+when the group is sharded.
 """
 from __future__ import annotations
 
+import dataclasses
 import hashlib
 import time
 from concurrent.futures import Future, ThreadPoolExecutor
@@ -101,6 +104,10 @@ class RunInfo:
     #: free, padded lanes repeat real systems): 0 = the no-host fast path
     host_trace_events: int = 0
     trace_gen_s: float = 0.0       # host trace/param staging wall-clock
+    #: the part of ``trace_gen_s`` spent placing the group's inputs on the
+    #: mesh (span ``stage.place``); 0 on one device, where the call takes
+    #: them as staged
+    place_s: float = 0.0
     groups: List[dict] = field(default_factory=list)
     shard_check: Optional[dict] = None
     #: span summary ``{name: {count, total_s}}`` from the installed
@@ -132,6 +139,7 @@ class RunInfo:
              "trace_backend": self.trace_backend,
              "host_trace_events": self.host_trace_events,
              "trace_gen_s": round(self.trace_gen_s, 4),
+             "place_s": round(self.place_s, 4),
              "us_per_event": round(self.us_per_call(), 4),
              "groups": self.groups}
         if self.xla_compiles >= 0:
@@ -219,11 +227,22 @@ class _GroupData:
     warm_start: np.ndarray     # (S,) int32
     host_trace_events: int = 0
     prep_s: float = 0.0
+    place_s: float = 0.0
+
+
+def _mesh(D: int):
+    """The one-axis mesh (``"dev"``) the sharded group program splits its
+    S axis over."""
+    from repro.parallel import compat
+    return compat.make_mesh((D,), ("dev",))
 
 
 def _prepare(points: Sequence[ResolvedPoint], idxs: Sequence[int],
              t_pad: int, warmup_frac: float,
-             trace_backend: str = "numpy") -> _GroupData:
+             trace_backend: str = "numpy", devices: int = 1) -> _GroupData:
+    """Stage one group's inputs. With ``devices`` > 1 they are placed on
+    the mesh in the sharded program's layout (span ``stage.place``), each
+    device's slice straight from the host."""
     import jax
     t0 = time.perf_counter()
     pts = [points[i] for i in idxs]
@@ -256,9 +275,10 @@ def _prepare(points: Sequence[ResolvedPoint], idxs: Sequence[int],
         per_system = [FamParams.of(pt.cfg, pt.flags, pt.policy_set())
                       for pt in pts]
     with maybe_span("stage.stack"):
-        # host numpy stack, then ONE transfer of the whole pytree (left
-        # uncommitted, so the shard_map path lays it out as it needs)
-        params = jax.device_put(stack_params(per_system))
+        params = stack_params(per_system)
+        if devices == 1:
+            # host numpy stack, then ONE transfer of the whole pytree
+            params = jax.device_put(params)
     # ``pt.t_true`` == pt.T unless the point is lifetime-gated (t_live,
     # e.g. an admission-throttled tenant): the traced masked-runner input
     # no-ops the non-live tail, never the compile key
@@ -267,9 +287,21 @@ def _prepare(points: Sequence[ResolvedPoint], idxs: Sequence[int],
     # ``int(T * warmup_frac)`` exactly
     warm_start = np.array([int(pt.t_true * warmup_frac) for pt in pts],
                           np.int32)
+    place_s = 0.0
+    if devices > 1:
+        from jax.sharding import NamedSharding
+        from jax.sharding import PartitionSpec as P
+        t1 = time.perf_counter()
+        with maybe_span("stage.place"):
+            # every leaf split over the mesh as the program takes it, and
+            # waited for, so the call starts with its inputs in place
+            params, inputs, t_true, warm_start = jax.block_until_ready(
+                jax.device_put((params, inputs, t_true, warm_start),
+                               NamedSharding(_mesh(devices), P("dev"))))
+        place_s = time.perf_counter() - t1
     return _GroupData(params, inputs, t_true, warm_start,
                       host_trace_events=host_events,
-                      prep_s=time.perf_counter() - t0)
+                      prep_s=time.perf_counter() - t0, place_s=place_s)
 
 
 # ---------------------------------------------------------------------------
@@ -396,8 +428,7 @@ def _compiled(cfg, S: int, N: int, t_pad: int, mode,
 
             from repro.parallel import compat
             _, D = mode
-            mesh = compat.make_mesh((D,), ("dev",))
-            fn = compat.shard_map(fn, mesh=mesh, in_specs=P("dev"),
+            fn = compat.shard_map(fn, mesh=_mesh(D), in_specs=P("dev"),
                                   out_specs=P("dev"))
         # every group executable is jitted under the canonical name
         # prefix so the runtime CompileWatcher (repro.analysis.runtime)
@@ -545,7 +576,7 @@ def execute(plan: Plan, *, devices: Optional[int] = None,
         def staged_prepare(gi_, t_pad_):
             with maybe_span("trace_stage", group=gi_):
                 return _prepare(plan.points, exec_idxs[gi_], t_pad_,
-                                warmup_frac, backend)
+                                warmup_frac, backend, devices=D)
 
         pending: Optional[Future] = None
         if pool is not None:
@@ -590,6 +621,7 @@ def execute(plan: Plan, *, devices: Optional[int] = None,
             info.padded_systems += S_exec - g.size
             info.host_trace_events += data.host_trace_events
             info.trace_gen_s += data.prep_s
+            info.place_s += data.place_s
             entry = {
                 "static_shape": str(g.key.static_shape),
                 "S": g.size, "S_exec": S_exec, "N": N, "T_pad": t_pad,
@@ -646,10 +678,19 @@ def _shard_cross_check(plan: Plan, data: _GroupData,
     against a run through the *other* path — shard_map vs vmap — bit-exact
     (the ROADMAP-mandated scale path must not change a single bit of any
     metric)."""
+    import jax
+
     g = plan.groups[0]
     rep = plan.points[g.indices[0]]
     S_exec, N, t_pad = len(idxs), g.key.num_nodes, g.t_pad
     alt_mode = "vmap" if primary_mode != "vmap" else ("shard", 1)
+    if primary_mode != "vmap":
+        # the primary's inputs are committed to the mesh; the one-device
+        # program takes host copies of them
+        params, inputs, t_true, warm_start = jax.device_get(
+            (data.params, data.inputs, data.t_true, data.warm_start))
+        data = dataclasses.replace(data, params=params, inputs=inputs,
+                                   t_true=t_true, warm_start=warm_start)
     alt = _run_group(data, _compiled(rep.cfg, S_exec, N, t_pad, alt_mode,
                                      pad_sets=g.pad_sets,
                                      pad_ways=g.pad_ways,

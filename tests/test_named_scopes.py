@@ -13,7 +13,7 @@ import jax
 import pytest
 
 SCOPES = ("trace_gen", "phase_a", "cache_lookup", "prefetcher", "sched",
-          "phase_c", "cache_fill", "metrics")
+          "phase_c", "cache_fill", "prefetch_queue", "metrics")
 
 
 def _compile(monkeypatch, telemetry: int):
@@ -62,3 +62,23 @@ def test_group_program_is_scoped_and_unchanged_by_scopes(monkeypatch,
         bare = _compile(monkeypatch, telemetry)
     assert not set(SCOPES) & _scopes_in(bare.as_text())
     assert _canonical(scoped) == _canonical(bare)
+
+
+def test_prefetch_queue_scope_nests_in_its_callers(monkeypatch):
+    """The queue's slot work carries ``prefetch_queue`` inside the scope
+    of its caller: the drain, the demand probe and the space gate under
+    ``phase_a``, the candidate probes under ``phase_a/prefetcher``, the
+    inserts under ``phase_c/cache_fill``."""
+    text = _compile(monkeypatch, 0).as_text()
+    callers = set()
+    for name in re.findall(r'op_name="([^"]*)"', text):
+        parts = []
+        for part in name.split("/"):
+            while part.startswith("vmap(") and part.endswith(")"):
+                part = part[5:-1]
+            parts.append(part)
+        if "prefetch_queue" in parts:
+            outer = parts[:parts.index("prefetch_queue")]
+            callers.add(tuple(p for p in outer if p in SCOPES))
+    assert {("phase_a",), ("phase_a", "prefetcher"),
+            ("phase_c", "cache_fill")} <= callers, sorted(callers)
