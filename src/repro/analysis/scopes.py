@@ -53,8 +53,10 @@ STATIC_ANNOTATIONS: FrozenSet[str] = frozenset({
 })
 
 #: attribute reads that yield static Python values off traced arrays
+#: and pytrees (``w_pad``: ``dram_cache.CacheState``'s allocated ways, a
+#: static field of the pytree)
 STATIC_ATTRS: FrozenSet[str] = frozenset({
-    "shape", "dtype", "ndim", "size", "at",
+    "shape", "dtype", "ndim", "size", "at", "w_pad",
 })
 
 

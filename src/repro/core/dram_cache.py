@@ -9,6 +9,20 @@ Functional jnp state -> jit/vmap/scan-safe; the same structure backs both
 the simulator and the production ``TieredBlockPool`` (where the "data" lives
 in an HBM block pool and slot index = HBM pool slot).
 
+**Storage.** The tag and recency tables are stored *lane-dense* —
+``(sets*ways/128, 128)``, each set's ways a contiguous segment of one
+128-lane row — whenever a set's ways tile the row evenly
+(``128 % ways == 0`` and ``sets*ways % 128 == 0``); otherwise they keep
+the ``(sets, ways)`` shape. The allocation's shape alone decides
+(:func:`storage`). Lane-dense tables keep one lanes-minor TPU layout
+through the simulator's loop, so a set is read as one 128-lane row
+gather and written back into that same layout (the whole row on a fill,
+one element on a touch); on the ``(sets, ways)`` shape a row gather next to element scatters makes the
+compiler relayout the whole table, so sets are read there one element per
+way (:func:`gather_row`). Both storages hold the same elements in the same
+(row-major) order: :func:`set_view` is a reshape to ``(sets, ways)``, and
+every operation returns the same values on either.
+
 **Padded geometry.** State arrays may be allocated at a *maximum* swept
 ``(num_sets, ways)`` while the effective geometry rides along as (possibly
 traced) ``num_sets``/``ways`` scalars on every operation: the set hash is
@@ -18,27 +32,69 @@ set indices never reach a padded row and way masks keep writes inside the
 effective ways, the padded region stays all-invalid forever and every
 operation is **bit-identical** to the same operation on an exactly-sized
 state (property-tested in ``tests/test_dram_cache_padded.py``). Passing
-``num_sets=None``/``ways=None`` (the default) uses the full array shape —
+``num_sets=None``/``ways=None`` (the default) uses the full allocation —
 the classic exact-geometry behaviour.
 """
 from __future__ import annotations
 
-from typing import NamedTuple, Tuple
+import dataclasses
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 
+#: lanes of one TPU vector register row: a lane-dense table's row width
+LANES = 128
 
-class CacheState(NamedTuple):
-    tags: jax.Array     # (sets, ways) int32: block_addr + 1; 0 = invalid
-    lru: jax.Array      # (sets, ways) int32: last-touch stamp
+
+@dataclasses.dataclass(frozen=True)
+class CacheState:
+    tags: jax.Array     # int32 block_addr + 1; 0 = invalid  (see storage)
+    lru: jax.Array      # int32 last-touch stamp, same shape as tags
     stamp: jax.Array    # () int32 monotonic counter
+    w_pad: int          # allocated ways per set (static)
+
+
+jax.tree_util.register_dataclass(CacheState,
+                                 data_fields=["tags", "lru", "stamp"],
+                                 meta_fields=["w_pad"])
+
+
+def storage(num_sets: int, ways: int) -> str:
+    """The tables' storage for an allocation of ``num_sets x ways``:
+    ``"lane_dense"`` (``(sets*ways/128, 128)``) when a set's ways tile a
+    128-lane row evenly, else ``"sets_ways"`` (``(sets, ways)``)."""
+    if LANES % ways == 0 and (num_sets * ways) % LANES == 0:
+        return "lane_dense"
+    return "sets_ways"
 
 
 def init_cache(num_sets: int, ways: int) -> CacheState:
-    return CacheState(tags=jnp.zeros((num_sets, ways), jnp.int32),
-                      lru=jnp.zeros((num_sets, ways), jnp.int32),
-                      stamp=jnp.zeros((), jnp.int32))
+    shape = ((num_sets * ways // LANES, LANES)
+             if storage(num_sets, ways) == "lane_dense"
+             else (num_sets, ways))
+    return CacheState(tags=jnp.zeros(shape, jnp.int32),
+                      lru=jnp.zeros(shape, jnp.int32),
+                      stamp=jnp.zeros((), jnp.int32), w_pad=ways)
+
+
+def set_view(state: CacheState) -> Tuple[jax.Array, jax.Array]:
+    """(tags, lru) as ``(..., sets, ways)`` tables, whatever the storage
+    (a reshape: both storages hold the elements in the same order)."""
+    shape = state.tags.shape[:-2] + (-1, state.w_pad)
+    return state.tags.reshape(shape), state.lru.reshape(shape)
+
+
+def _num_sets(state: CacheState) -> int:
+    return state.tags.shape[-2] * state.tags.shape[-1] // state.w_pad
+
+
+def _locate(state: CacheState, si):
+    """(row, lane offset) of set ``si``'s ways in the stored tables."""
+    k = state.tags.shape[-1] // state.w_pad       # sets per stored row
+    if k == 1:
+        return si, 0
+    return si // k, (si % k) * state.w_pad
 
 
 def _set_index(block_addr, num_sets):
@@ -50,7 +106,7 @@ def _set_index(block_addr, num_sets):
 
 def _way_mask(state: CacheState, ways):
     """(W_pad,) bool: True for the effective ways (``ways`` may be traced)."""
-    return jnp.arange(state.tags.shape[1]) < jnp.asarray(ways)
+    return jnp.arange(state.w_pad) < jnp.asarray(ways)
 
 
 def gather_row(a, i):
@@ -64,21 +120,60 @@ def gather_row(a, i):
     return a[i, jnp.arange(a.shape[1])]
 
 
+def _read_row(table, row):
+    """Stored row ``row`` of ``table`` (tags or lru): one 128-lane row
+    gather when lane-dense, one element gather per way on the
+    ``(sets, ways)`` shape."""
+    if table.shape[-1] != LANES:
+        return gather_row(table, row)
+    return table[row]
+
+
+def _own_lanes(state: CacheState, si, ways):
+    """Which lanes of set ``si``'s stored row are its effective ways:
+    its own ``W_pad`` segment (lane-dense), cut to the first ``ways``;
+    ``None`` when that is every lane."""
+    lane = jnp.arange(state.tags.shape[-1])
+    k = state.tags.shape[-1] // state.w_pad
+    own = None if k == 1 else lane // state.w_pad == si % k
+    if ways is not None:
+        in_ways = lane % state.w_pad < jnp.asarray(ways)
+        own = in_ways if own is None else own & in_ways
+    return own
+
+
+def _segment(state: CacheState, row, si):
+    """Set ``si``'s ``W_pad`` ways out of its stored ``row``, by a masked
+    sum over the row's sets (a lane select, never a gather)."""
+    k = state.tags.shape[-1] // state.w_pad
+    if k == 1:
+        return row
+    segs = row.reshape(k, state.w_pad)
+    return jnp.sum(jnp.where((jnp.arange(k) == si % k)[:, None], segs, 0),
+                   axis=0)
+
+
+def _pick(row, i):
+    """``row[i]`` as a masked sum: a lane select, never a gather."""
+    return jnp.sum(jnp.where(jnp.arange(row.shape[-1]) == i, row, 0))
+
+
 def lookup(state: CacheState, block_addr, num_sets=None, ways=None
            ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """-> (hit, set_idx, way). Pure query; no state change.
 
     ``num_sets``/``ways`` give the effective geometry of a padded state
-    (both may be traced scalars); ``None`` uses the full array shape.
+    (both may be traced scalars); ``None`` uses the full allocation.
     """
     si = _set_index(block_addr,
-                    state.tags.shape[0] if num_sets is None else num_sets)
-    row = gather_row(state.tags, si)
+                    _num_sets(state) if num_sets is None else num_sets)
+    row = _read_row(state.tags, _locate(state, si)[0])
     match = row == (block_addr.astype(jnp.int32) + 1)
-    if ways is not None:
-        match = match & _way_mask(state, ways)
+    own = _own_lanes(state, si, ways)
+    if own is not None:
+        match = match & own
     hit = jnp.any(match)
-    way = jnp.argmax(match).astype(jnp.int32)
+    way = (jnp.argmax(match) % state.w_pad).astype(jnp.int32)
     return hit, si, way
 
 
@@ -92,11 +187,12 @@ def touch(state: CacheState, set_idx, way, enable=True,
     hit-time recency value; ``None`` is the classic LRU stamp."""
     en = jnp.asarray(enable)
     stamp = state.stamp + en.astype(jnp.int32)
-    old = state.lru[set_idx, way]
+    r, off = _locate(state, set_idx)
+    old = state.lru[r, off + way]
     hit_val = stamp if policy is None else policy.on_hit(old, stamp)
     new_lru = jnp.where(en, hit_val, old)
-    return state._replace(lru=state.lru.at[set_idx, way].set(new_lru),
-                          stamp=stamp)
+    return dataclasses.replace(
+        state, lru=state.lru.at[r, off + way].set(new_lru), stamp=stamp)
 
 
 def insert(state: CacheState, block_addr, enable=True,
@@ -110,64 +206,79 @@ def insert(state: CacheState, block_addr, enable=True,
     ``num_sets``/``ways`` give the effective geometry of a padded state:
     vacancy and victim selection never consider a padded way.
 
-    ``policy=None`` keeps the classic single-element in-place set-LRU path
-    (the pre-policy program, bit for bit). A bound replacement policy
+    The set's tags and recency are read once each, as the stored rows
+    that hold the set; hit, vacancy and the LRU victim are found on the
+    set's own lanes of those rows. ``policy=None`` keeps the classic
+    in-place set-LRU path (the pre-policy results, bit for bit), writing
+    the rows back whole when lane-dense and one element per table on the
+    ``(sets, ways)`` shape. A bound replacement policy
     (``repro.policies.replacement``) switches to the generalized path:
-    the policy may age the whole recency row on eviction (SRRIP) and
-    chooses the victim way; hit/vacancy handling is shared.
+    the policy may age the set's whole recency segment on eviction
+    (SRRIP) and chooses the victim way; hit/vacancy handling is shared.
     """
     en = jnp.asarray(enable)
     si = _set_index(block_addr,
-                    state.tags.shape[0] if num_sets is None else num_sets)
-    row_tags = gather_row(state.tags, si)
-    row_lru = gather_row(state.lru, si)
+                    _num_sets(state) if num_sets is None else num_sets)
+    r, off = _locate(state, si)
+    row_tags = _read_row(state.tags, r)
+    row_lru = _read_row(state.lru, r)
     tag = block_addr.astype(jnp.int32) + 1
+    own = _own_lanes(state, si, ways)
     already = row_tags == tag
     vacant = row_tags == 0
-    victim_lru = row_lru
-    wmask = None
-    if ways is not None:
-        wmask = _way_mask(state, ways)
-        already = already & wmask
-        vacant = vacant & wmask
-        victim_lru = jnp.where(wmask, row_lru, jnp.iinfo(jnp.int32).max)
+    if own is not None:
+        already = already & own
+        vacant = vacant & own
     has = jnp.any(already)
     has_vacant = jnp.any(vacant)
     stamp = state.stamp + en.astype(jnp.int32)
-    w_pad = state.tags.shape[1]
+    w_pad = state.w_pad
     if policy is None:
-        way = jnp.where(has, jnp.argmax(already),
-                        jnp.where(has_vacant, jnp.argmax(vacant),
-                                  jnp.argmin(victim_lru))).astype(jnp.int32)
-        evicted = jnp.where(en & ~(has | has_vacant), row_tags[way] - 1, -1)
-        new = CacheState(
-            tags=state.tags.at[si, way].set(jnp.where(en, tag,
-                                                      row_tags[way])),
-            lru=state.lru.at[si, way].set(jnp.where(en, stamp,
-                                                    row_lru[way])),
-            stamp=stamp)
+        victim_lru = row_lru if own is None else \
+            jnp.where(own, row_lru, jnp.iinfo(jnp.int32).max)
+        way = (jnp.where(has, jnp.argmax(already),
+                         jnp.where(has_vacant, jnp.argmax(vacant),
+                                   jnp.argmin(victim_lru))) % w_pad
+               ).astype(jnp.int32)
+        old_tag = _pick(row_tags, off + way)
+        evicted = jnp.where(en & ~(has | has_vacant), old_tag - 1, -1)
+        if state.tags.shape[-1] == LANES:
+            # the rows go back whole, the one way replaced: on a v5e that
+            # ran fig08's grid 12 % faster than two element scatters
+            lane = en & (jnp.arange(LANES) == off + way)
+            tags = state.tags.at[r].set(jnp.where(lane, tag, row_tags))
+            lru = state.lru.at[r].set(jnp.where(lane, stamp, row_lru))
+        else:
+            tags = state.tags.at[r, off + way].set(
+                jnp.where(en, tag, old_tag))
+            lru = state.lru.at[r, off + way].set(
+                jnp.where(en, stamp, _pick(row_lru, off + way)))
+        new = dataclasses.replace(state, tags=tags, lru=lru, stamp=stamp)
         return new, evicted, si * w_pad + way
 
-    if wmask is None:
-        wmask = jnp.ones((w_pad,), jnp.bool_)
+    seg_lru = _segment(state, row_lru, si)
+    wmask = (jnp.ones((w_pad,), jnp.bool_) if ways is None
+             else _way_mask(state, ways))
     eff_ways = jnp.asarray(w_pad if ways is None else ways, jnp.int32)
-    aged_row, evict_way = policy.evict(row_lru, wmask, stamp, si, eff_ways)
-    way = jnp.where(has, jnp.argmax(already),
-                    jnp.where(has_vacant, jnp.argmax(vacant),
+    aged_row, evict_way = policy.evict(seg_lru, wmask, stamp, si, eff_ways)
+    way = jnp.where(has, jnp.argmax(already) % w_pad,
+                    jnp.where(has_vacant, jnp.argmax(vacant) % w_pad,
                               evict_way)).astype(jnp.int32)
-    evicted = jnp.where(en & ~(has | has_vacant), row_tags[way] - 1, -1)
+    old_tag = _pick(row_tags, off + way)
+    evicted = jnp.where(en & ~(has | has_vacant), old_tag - 1, -1)
     # aging applies only on the eviction path; hit/vacancy keep the row.
     # A redundant fill of an already-present block is a re-reference —
     # the policy's hit update (promote), never a fresh-insert value
     # (which would DEMOTE a hot line under SRRIP).
-    base_row = jnp.where(has | has_vacant, row_lru, aged_row)
-    fill_val = jnp.where(has, policy.on_hit(row_lru[way], stamp),
+    base_row = jnp.where(has | has_vacant, seg_lru, aged_row)
+    fill_val = jnp.where(has, policy.on_hit(_pick(seg_lru, way), stamp),
                          policy.insert_value(stamp))
     new_row = base_row.at[way].set(fill_val)
-    new = CacheState(
-        tags=state.tags.at[si, way].set(jnp.where(en, tag, row_tags[way])),
-        lru=state.lru.at[si, jnp.arange(w_pad)].set(
-            jnp.where(en, new_row, row_lru)),
+    new = dataclasses.replace(
+        state,
+        tags=state.tags.at[r, off + way].set(jnp.where(en, tag, old_tag)),
+        lru=state.lru.at[r, off + jnp.arange(w_pad)].set(
+            jnp.where(en, new_row, seg_lru)),
         stamp=stamp)
     return new, evicted, si * w_pad + way
 
@@ -175,8 +286,9 @@ def insert(state: CacheState, block_addr, enable=True,
 def invalidate(state: CacheState, block_addr, num_sets=None, ways=None
                ) -> CacheState:
     hit, si, way = lookup(state, block_addr, num_sets=num_sets, ways=ways)
-    tags = jnp.where(hit, state.tags.at[si, way].set(0), state.tags)
-    return state._replace(tags=tags)
+    r, off = _locate(state, si)
+    tags = jnp.where(hit, state.tags.at[r, off + way].set(0), state.tags)
+    return dataclasses.replace(state, tags=tags)
 
 
 def occupancy(state: CacheState, num_sets=None, ways=None) -> jax.Array:
@@ -184,15 +296,15 @@ def occupancy(state: CacheState, num_sets=None, ways=None) -> jax.Array:
 
     The padded region never holds tags (see module docstring), so the sum
     over the full array equals the sum over the effective region, and the
-    divisor uses the effective entry count — the quotient is bit-identical
-    to ``jnp.mean`` over an exactly-sized state (0/1 partial sums are
-    integers, exact in f32 below 2**24 entries).
+    divisor is the effective entry count — one division of the same two
+    numbers whatever the allocation, so the quotient is bit-identical to
+    the one of an exactly-sized state (0/1 partial sums are integers,
+    exact in f32 below 2**24 entries). ``jnp.mean`` is not used: it may
+    multiply by the reciprocal instead, which rounds differently.
     """
-    filled = (state.tags > 0).astype(jnp.float32)
-    if num_sets is None and ways is None:
-        return jnp.mean(filled)
-    num_sets = state.tags.shape[0] if num_sets is None else num_sets
-    ways = state.tags.shape[1] if ways is None else ways
+    tags, _ = set_view(state)
+    num_sets = tags.shape[0] if num_sets is None else num_sets
+    ways = tags.shape[1] if ways is None else ways
     total = (jnp.asarray(num_sets, jnp.int32) *
              jnp.asarray(ways, jnp.int32)).astype(jnp.float32)
-    return jnp.sum(filled) / total
+    return jnp.sum((tags > 0).astype(jnp.float32)) / total

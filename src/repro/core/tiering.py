@@ -195,10 +195,11 @@ class TieredBlockPool:
         probe; interpreted off-TPU) or the pure-XLA reference — bit-
         identical either way (tests/test_kernels.py)."""
         ids = ids.astype(jnp.int32)
+        tags, _ = dc.set_view(st.cache)
         if self.cfg.kernel_backend == "pallas":
-            return cache_lookup(st.cache.tags, ids,
+            return cache_lookup(tags, ids,
                                 interpret=jax.default_backend() != "tpu")
-        return cache_lookup_ref(st.cache.tags, ids)
+        return cache_lookup_ref(tags, ids)
 
     def read(self, st: TierState, slots: jax.Array) -> jax.Array:
         """Gather blocks from the fast region. ``cfg.kernel_backend``

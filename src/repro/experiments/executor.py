@@ -49,6 +49,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.core.dram_cache import storage as cache_storage
 from repro.core.fam_params import FamParams, stack_params
 from repro.core.famsim import build_masked_vmap
 from repro.experiments.plan import CompileGroup, Plan, s_bucket
@@ -108,6 +109,9 @@ class RunInfo:
     #: mesh (span ``stage.place``); 0 on one device, where the call takes
     #: them as staged
     place_s: float = 0.0
+    #: one row per executed group: its shape, padding, the storage of its
+    #: cache tables (``"lane_dense"`` or ``"sets_ways"``,
+    #: ``repro.core.dram_cache.storage``) and its compile/run accounting
     groups: List[dict] = field(default_factory=list)
     shard_check: Optional[dict] = None
     #: span summary ``{name: {count, total_s}}`` from the installed
@@ -626,6 +630,7 @@ def execute(plan: Plan, *, devices: Optional[int] = None,
                 "static_shape": str(g.key.static_shape),
                 "S": g.size, "S_exec": S_exec, "N": N, "T_pad": t_pad,
                 "pad_sets": g.pad_sets, "pad_ways": g.pad_ways,
+                "cache_storage": cache_storage(g.pad_sets, g.pad_ways),
                 "compile_s": round(compile_s, 3), "run_s": round(run_s, 3),
                 "fresh_compile": info.compiles > before,
                 "exec_cache_hit": pre_warm[gi],

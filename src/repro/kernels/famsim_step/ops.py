@@ -13,6 +13,7 @@ only policies that declare ``fused_mode`` ("lru", "srrip") can ride it;
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
@@ -58,10 +59,14 @@ def cache_step(cache: dc.CacheState, fill_blocks, fill_enable,
         return functools.partial(fused_cache_step, mode=mode,
                                  max_rrpv=max_rrpv, interpret=interpret)
 
+    # the kernel works on (sets, ways) tables, whatever the storage;
     # chosen by the platform the program is lowered for: compiled on TPU,
     # interpreted anywhere else
     tags, lru, stamp, hit, probe_hits = jax.lax.platform_dependent(
-        cache.tags, cache.lru, cache.stamp, fill_blocks, fill_enable,
+        *dc.set_view(cache), cache.stamp, fill_blocks, fill_enable,
         demand_block, demand_enable, probe_blocks, num_sets, ways,
         tpu=kernel(False), default=kernel(True))
-    return dc.CacheState(tags, lru, stamp), hit, probe_hits
+    stored = cache.tags.shape
+    return (dataclasses.replace(cache, tags=tags.reshape(stored),
+                                lru=lru.reshape(stored), stamp=stamp),
+            hit, probe_hits)

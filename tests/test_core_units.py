@@ -88,35 +88,53 @@ def test_dyn_address_decomposition_matches_static():
 # DRAM cache
 # ---------------------------------------------------------------------------
 
-def test_cache_insert_lookup_lru():
-    st = dc.init_cache(4, 2)
+#: per storage, an allocation init_cache stores that way
+CACHE_GEOM = {"sets_ways": (4, 2), "lane_dense": (64, 16)}
+
+
+@pytest.mark.parametrize("storage", sorted(CACHE_GEOM))
+def test_cache_insert_lookup_lru(storage):
+    st = dc.init_cache(*CACHE_GEOM[storage])
+    assert dc.storage(*CACHE_GEOM[storage]) == storage
     st, ev, slot = dc.insert(st, jnp.int32(10))
     assert int(ev) == -1
     hit, si, way = dc.lookup(st, jnp.int32(10))
     assert bool(hit)
+    assert int(slot) == int(si) * st.w_pad + int(way)
+    assert int(dc.set_view(st)[0][si, way]) == 11
     hit2, _, _ = dc.lookup(st, jnp.int32(11))
     assert not bool(hit2)
 
 
-def test_cache_lru_eviction_order():
-    st = dc.init_cache(1, 2)  # one set, two ways
-    st, _, _ = dc.insert(st, jnp.int32(1))
-    st, _, _ = dc.insert(st, jnp.int32(2))
+@pytest.mark.parametrize("storage", ["sets_ways", "lane_dense"])
+def test_cache_lru_eviction_order(storage):
+    # one set, two ways: the whole allocation, or the one effective set
+    # of a lane-dense allocation of 64 two-way sets
+    if storage == "sets_ways":
+        st, kw = dc.init_cache(1, 2), {}
+    else:
+        st, kw = dc.init_cache(64, 2), dict(num_sets=1)
+    assert dc.storage(*dc.set_view(st)[0].shape) == storage
+    st, _, _ = dc.insert(st, jnp.int32(1), **kw)
+    st, _, _ = dc.insert(st, jnp.int32(2), **kw)
     # touch 1 so 2 becomes LRU
-    hit, si, way = dc.lookup(st, jnp.int32(1))
+    hit, si, way = dc.lookup(st, jnp.int32(1), **kw)
     st = dc.touch(st, si, way)
-    st, evicted, _ = dc.insert(st, jnp.int32(3))
+    st, evicted, _ = dc.insert(st, jnp.int32(3), **kw)
     assert int(evicted) == 2
 
 
+@pytest.mark.parametrize("storage,geom", [("sets_ways", (8, 4)),
+                                          ("lane_dense", (32, 4))])
 @settings(max_examples=15, deadline=None)
 @given(seed=st.integers(0, 1000))
-def test_cache_never_duplicates(seed):
+def test_cache_never_duplicates(storage, geom, seed):
+    assert dc.storage(*geom) == storage
     rng = np.random.default_rng(seed)
-    st_ = dc.init_cache(8, 4)
+    st_ = dc.init_cache(*geom)
     for a in rng.integers(0, 100, 60):
         st_, _, _ = dc.insert(st_, jnp.int32(int(a)))
-        tags = np.asarray(st_.tags).ravel()
+        tags = np.asarray(dc.set_view(st_)[0]).ravel()
         tags = tags[tags > 0]
         assert len(set(tags.tolist())) == len(tags)
 

@@ -3,34 +3,62 @@ larger ``(num_sets, ways)`` allocation — with the effective geometry passed
 as scalars — must be bit-identical to the same operation on an exactly
 sized state, across randomized insert/touch/invalidate/occupancy sequences
 (the foundation of the planner's one-group-per-figure guarantee), and the
-padded region must stay invalid forever."""
+padded region must stay invalid forever. The padded state runs on either
+storage of the tables (lane-dense or ``(sets, ways)``), compared through
+``dc.set_view``."""
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.core import dram_cache as dc
 
-GEOMETRIES = [
-    # (sets, ways, pad_sets, pad_ways)
+# (sets, ways, pad_sets, pad_ways)
+TINY = [
     (4, 2, 4, 2),          # no padding: kwargs must be pure identity
     (4, 2, 16, 2),         # padded sets only
     (8, 4, 8, 8),          # padded ways only
-    (2, 2, 64, 16),        # both, heavily
     (1, 1, 8, 4),          # degenerate direct-mapped single set
 ]
+# allocations whose sets tile 128-lane rows: init_cache stores them
+# lane-dense, and the same geometry can be held as (sets, ways) too
+ELIGIBLE = [
+    (40, 12, 64, 16),      # both padded, as the figure grids pad
+    (2, 2, 64, 16),        # both, heavily
+    (64, 16, 64, 16),      # no padding
+    (5, 3, 32, 4),         # 32 sets to a row
+]
+GEOMETRIES = {"sets_ways": TINY + ELIGIBLE, "lane_dense": ELIGIBLE}
 
 
-def _run_sequence(sets, ways, pad_sets, pad_ways, ops):
+def sets_ways_state(num_sets, ways):
+    """An empty cache held as ``(sets, ways)`` tables whatever its shape
+    (init_cache would store an eligible allocation lane-dense)."""
+    z = jnp.zeros((num_sets, ways), jnp.int32)
+    return dc.CacheState(tags=z, lru=z, stamp=jnp.zeros((), jnp.int32),
+                         w_pad=ways)
+
+
+def _padded_state(storage, pad_sets, pad_ways):
+    if storage == "lane_dense":
+        st_ = dc.init_cache(pad_sets, pad_ways)
+        assert st_.tags.shape == (pad_sets * pad_ways // 128, 128)
+        return st_
+    return sets_ways_state(pad_sets, pad_ways)
+
+
+def _run_sequence(sets, ways, pad_sets, pad_ways, ops, storage="sets_ways"):
     """Drive exact and padded states through one op sequence, asserting
     every returned value and the effective state region match bit-for-bit
     after each op."""
-    exact = dc.init_cache(sets, ways)
-    padded = dc.init_cache(pad_sets, pad_ways)
+    exact = sets_ways_state(sets, ways)
+    padded = _padded_state(storage, pad_sets, pad_ways)
     kw = dict(num_sets=sets, ways=ways)
 
     def check(tag):
-        e_tags, p_tags = np.asarray(exact.tags), np.asarray(padded.tags)
-        e_lru, p_lru = np.asarray(exact.lru), np.asarray(padded.lru)
+        assert padded.tags.shape == padded.lru.shape
+        e_tags, e_lru = map(np.asarray, dc.set_view(exact))
+        p_tags, p_lru = map(np.asarray, dc.set_view(padded))
         np.testing.assert_array_equal(e_tags, p_tags[:sets, :ways], tag)
         np.testing.assert_array_equal(e_lru, p_lru[:sets, :ways], tag)
         assert int(exact.stamp) == int(padded.stamp), tag
@@ -63,24 +91,31 @@ def _run_sequence(sets, ways, pad_sets, pad_ways, ops):
         check((op, addr))
 
 
+@pytest.mark.parametrize("storage", ["sets_ways", "lane_dense"])
 @settings(max_examples=10, deadline=None)
-@given(seed=st.integers(0, 10_000), geom=st.sampled_from(GEOMETRIES))
-def test_padded_equals_exact_random_sequences(seed, geom):
+@given(data=st.data())
+def test_padded_equals_exact_random_sequences(storage, data):
+    seed = data.draw(st.integers(0, 10_000))
+    sets, ways, pad_sets, pad_ways = data.draw(
+        st.sampled_from(GEOMETRIES[storage]))
     rng = np.random.default_rng(seed)
-    sets, ways, pad_sets, pad_ways = geom
     # enough distinct addresses to force evictions in every set
     n_addr = 4 * sets * ways + 8
     ops = []
     for _ in range(40):
         kind = ["insert", "insert", "probe", "invalidate"][rng.integers(4)]
         ops.append((kind, int(rng.integers(0, n_addr))))
-    _run_sequence(sets, ways, pad_sets, pad_ways, ops)
+    _run_sequence(sets, ways, pad_sets, pad_ways, ops, storage)
 
 
-def test_eviction_ignores_padded_ways():
+@pytest.mark.parametrize("pad_sets", [1, 16])
+def test_eviction_ignores_padded_ways(pad_sets):
     """A full effective set must evict its LRU member even when padded
-    ways sit empty next to it (vacancy must not leak into the padding)."""
-    st_ = dc.init_cache(1, 8)        # padded to 8 ways
+    ways sit empty next to it (vacancy must not leak into the padding) —
+    on a one-set allocation and on a lane-dense one of 16 sets."""
+    st_ = dc.init_cache(pad_sets, 8)  # padded to 8 ways
+    assert dc.storage(pad_sets, 8) == ("lane_dense" if pad_sets == 16
+                                       else "sets_ways")
     kw = dict(num_sets=1, ways=2)    # effective: 1 set, 2 ways
     st_, _, _ = dc.insert(st_, jnp.int32(1), **kw)
     st_, _, _ = dc.insert(st_, jnp.int32(2), **kw)
@@ -88,7 +123,27 @@ def test_eviction_ignores_padded_ways():
     st_ = dc.touch(st_, si, way, enable=hit)      # 2 becomes LRU
     st_, evicted, _ = dc.insert(st_, jnp.int32(3), **kw)
     assert int(evicted) == 2
-    assert (np.asarray(st_.tags)[:, 2:] == 0).all()
+    assert (np.asarray(dc.set_view(st_)[0])[:, 2:] == 0).all()
+
+
+@pytest.mark.parametrize("num_sets,ways,storage,shape", [
+    (16384, 16, "lane_dense", (2048, 128)),     # fig08's allocation
+    (4096, 16, "lane_dense", (512, 128)),
+    (64, 2, "lane_dense", (1, 128)),
+    (1, 128, "lane_dense", (1, 128)),
+    (4, 2, "sets_ways", (4, 2)),                # under one row
+    (40, 12, "sets_ways", (40, 12)),            # 12 does not divide 128
+    (8, 256, "sets_ways", (8, 256)),            # wider than a row
+])
+def test_init_cache_picks_storage_by_shape(num_sets, ways, storage, shape):
+    """The allocation's (sets, ways) alone decides the storage; the
+    logical view is (sets, ways) either way."""
+    st_ = dc.init_cache(num_sets, ways)
+    assert dc.storage(num_sets, ways) == storage
+    assert st_.tags.shape == st_.lru.shape == shape
+    assert st_.w_pad == ways
+    tags, lru = dc.set_view(st_)
+    assert tags.shape == lru.shape == (num_sets, ways)
 
 
 def test_set_hash_modulo_effective_sets():

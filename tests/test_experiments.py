@@ -16,6 +16,7 @@ import numpy as np
 import pytest
 
 from repro.configs.base import FamConfig, fam_replace
+from repro.core import dram_cache as dc
 from repro.core.famsim import SimFlags, build_sim
 from repro.core.traces import generate, node_seed
 from repro.experiments import (Axis, AxisValue, Experiment, config_axis,
@@ -440,6 +441,7 @@ def test_info_records_per_group_wallclock(small_result):
     g = info.groups[0]
     for field in ("compile_s", "run_s", "S", "N", "T_pad", "static_shape"):
         assert field in g
+    assert g["cache_storage"] == dc.storage(g["pad_sets"], g["pad_ways"])
     assert g["T_pad"] == T
     assert info.events == 4 * 1 * T
     assert info.padded_events == 0          # uniform-T: no padding paid
@@ -562,6 +564,25 @@ def test_shim_warns_exactly_once_per_process():
     dep = [w for w in rec if issubclass(w.category, DeprecationWarning)
            and "run_points" in str(w.message)]
     assert len(dep) == 1, [str(w.message) for w in rec]
+
+
+def test_run_info_records_cache_storage():
+    """Each group row of ``RunInfo`` names the storage its cache tables
+    used: fig08's quick plan pads every point to 16,384 sets x 16 ways,
+    which are stored lane-dense."""
+    import dataclasses
+
+    from benchmarks import fig08_blocksize
+
+    exp = fig08_blocksize.experiment(quick=True)
+    small = dataclasses.replace(
+        exp, T=64, axes=tuple(dataclasses.replace(a, values=a.values[:1])
+                              if a.name == "workload" else a
+                              for a in exp.axes))
+    plan = small.plan()
+    assert [(g.pad_sets, g.pad_ways) for g in plan.groups] == [(16384, 16)]
+    groups = execute(plan).info.as_dict()["groups"]
+    assert [g["cache_storage"] for g in groups] == ["lane_dense"]
 
 
 def test_runtime_compile_count_matches_plan_for_fig08_fig16():

@@ -47,17 +47,35 @@ def _node_traces(T=T):
 # kernel vs reference: driven op streams over random padded geometries
 # ---------------------------------------------------------------------------
 
+#: per storage of the tables, padded allocations (sets, ways) init_cache
+#: stores that way: lane-dense when the sets tile 128-lane rows
+PAD_GEOMS = {
+    "sets_ways": [(s, w) for s in (4, 8, 16) for w in (2, 4, 8)
+                  if s * w % 128],
+    "lane_dense": [(64, 16), (16, 8), (32, 4)],
+}
+
+
+def assert_same_cache(a, b):
+    """Same storage, and the same (sets, ways) tables and stamp."""
+    assert a.tags.shape == b.tags.shape and a.w_pad == b.w_pad
+    for x, y in zip(dc.set_view(a) + (a.stamp,), dc.set_view(b) + (b.stamp,)):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+@pytest.mark.parametrize("storage", sorted(PAD_GEOMS))
 @settings(max_examples=12, deadline=None)
-@given(pad_sets=st.sampled_from([4, 8, 16]),
-       pad_ways=st.sampled_from([2, 4, 8]),
-       sets_frac=st.floats(0.25, 1.0), ways_frac=st.floats(0.25, 1.0),
-       srrip=st.booleans(), c=st.integers(1, 4), p=st.integers(1, 6),
-       seed=st.integers(0, 2 ** 16))
-def test_fused_cache_step_property(pad_sets, pad_ways, sets_frac, ways_frac,
+@given(data=st.data(), sets_frac=st.floats(0.25, 1.0),
+       ways_frac=st.floats(0.25, 1.0), srrip=st.booleans(),
+       c=st.integers(1, 4), p=st.integers(1, 6), seed=st.integers(0, 2 ** 16))
+def test_fused_cache_step_property(storage, data, sets_frac, ways_frac,
                                    srrip, c, p, seed):
     """Fused kernel == reference, state and outputs, on every step of a
     random op stream — effective geometry strictly below the padding
-    exercises the dynamic-ways mask and the modulo set hash."""
+    exercises the dynamic-ways mask and the modulo set hash; the kernel
+    sees the reference's tables through ``dc.set_view``."""
+    pad_sets, pad_ways = data.draw(st.sampled_from(PAD_GEOMS[storage]))
+    assert dc.storage(pad_sets, pad_ways) == storage
     eff_sets = max(1, int(pad_sets * sets_frac))
     eff_ways = max(1, int(pad_ways * ways_frac))
     policy = SRRIP.bind(None) if srrip else None
@@ -76,8 +94,7 @@ def test_fused_cache_step_property(pad_sets, pad_ways, sets_frac, ways_frac,
         np.testing.assert_array_equal(np.asarray(rhit), np.asarray(fhit))
         np.testing.assert_array_equal(np.asarray(rprobes),
                                       np.asarray(fprobes))
-        for a, b in zip(ref, fused):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        assert_same_cache(ref, fused)
 
 
 def test_fused_cache_step_raw_wrapper_shapes():
@@ -117,9 +134,9 @@ def test_fused_cache_step_nested_vmap():
 
     def run(step):
         def node(t, l, st, f, fe, d, de, p, ns, w):
-            c, hit, ph = step(dc.CacheState(t, l, st), f, fe, d, de, p,
-                              ns, w)
-            return (*c, hit, ph)
+            c, hit, ph = step(dc.CacheState(t, l, st, w_pad=4), f, fe, d,
+                              de, p, ns, w)
+            return c.tags, c.lru, c.stamp, hit, ph
         per_system = jax.vmap(node, in_axes=(0,) * 8 + (None, None))
         return jax.vmap(per_system)(tags, lru, stamp, fills, fen, demand,
                                     den, probes, sets, ways)

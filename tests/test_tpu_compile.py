@@ -72,10 +72,12 @@ def test_fused_cache_step_compiles_for_v5e(one_chip, no_persistent_cache,
     S, W = cfg.num_sets, cfg.cache_ways
     C = cfg.completions_per_step
     P = cfg.prefetch_degree + cfg.core_pf_degree
-    cache = jax.eval_shape(lambda: dc.init_cache(S, W))
+    # the kernel takes the (sets, ways) view of the tables
+    tables = jax.eval_shape(
+        lambda: (lambda c: (*dc.set_view(c), c.stamp))(dc.init_cache(S, W)))
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
     b = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bool_)
-    args = _on(one_chip, (*cache, i32(C), b(C), i32(), b(), i32(P), i32(),
+    args = _on(one_chip, (*tables, i32(C), b(C), i32(), b(), i32(P), i32(),
                           i32()))
     compiled = fused_cache_step.lower(*args, mode=mode,
                                       max_rrpv=max_rrpv).compile()
@@ -83,14 +85,22 @@ def test_fused_cache_step_compiles_for_v5e(one_chip, no_persistent_cache,
     assert "tpu_custom_call" in compiled.as_text()
 
 
+#: temp bytes of the xla group below when its cache tables were stored
+#: (sets, ways) and read one element per way
+SETS_WAYS_TEMP_BYTES = 144_536_576
+
+
 @pytest.mark.parametrize("backend", ["pallas", "xla"])
 def test_group_compiles_for_v5e(one_chip, no_persistent_cache, backend):
     """fig08's quick grid as the executor builds it (one vmapped group,
     S = 80, T = 12,000, in-graph traces). On pallas the compiled text
     carries the kernel, not its interpreted form. On xla no instruction
-    copies a whole cache array: a row gather and an element scatter that
-    disagree on the layout make the compiler relayout the tags and
-    recency on every fill, which took 99 % of the step."""
+    copies a whole cache array, in either storage: a row gather and an
+    element scatter that disagree on the layout make the compiler
+    relayout the tags and recency on every fill, which took 99 % of the
+    step. The lane-dense tables are read as 128-lane rows, never as
+    S x 16 element gathers, and need no more temp memory than the
+    (sets, ways) tables did."""
     import repro.core.famsim  # noqa: F401  (repro.core before the kernels)
     from benchmarks import fig08_blocksize
     from repro.experiments import executor as ex
@@ -110,7 +120,14 @@ def test_group_compiles_for_v5e(one_chip, no_persistent_cache, backend):
     if backend == "pallas":
         assert "tpu_custom_call" in text
     else:
-        cache = re.compile(r"= s32\[80,1,16384,16\]\S* copy\(")
+        cache = re.compile(r"= s32\[80,1,(16384,16|2048,128)\]\S* copy\(")
         assert not cache.findall(text)
+        # S * W_pad table elements gathered one by one, fused or not
+        per_way = re.compile(r"= s32\[(1280|80,16)\]\S* (gather|fusion)\("
+                             r'.*op_name="[^"]*cache_lookup[^"]*gather"')
+        assert not per_way.findall(text)
+        assert re.search(r"= s32\[80,128\]\S* gather\(", text)
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 16e9           # one v5e chip's HBM
+    if backend == "xla":
+        assert mem.temp_size_in_bytes <= SETS_WAYS_TEMP_BYTES
